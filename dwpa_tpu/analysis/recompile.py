@@ -35,11 +35,10 @@ import jax
 
 #: emitted by jax._src.dispatch once per compile-cache miss
 _COMPILE_RE = re.compile(r"Finished XLA compilation of ([^\s]+) in")
-#: loggers that carry the compile events across the jax versions we span
-#: (pxla only adds "Compiling <name> ..." noise — attached so propagation
-#: pausing silences it too; the count regex never matches its messages)
-_LOGGER_NAMES = ("jax._src.dispatch", "jax.dispatch",
-                 "jax._src.interpreters.pxla")
+#: loggers that carry the compile events (pxla only adds "Compiling
+#: <name> ..." noise — attached so propagation pausing silences it too;
+#: the count regex never matches its messages)
+_LOGGER_NAMES = ("jax._src.dispatch", "jax._src.interpreters.pxla")
 
 
 class RecompilationError(AssertionError):

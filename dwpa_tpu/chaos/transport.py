@@ -2,7 +2,7 @@
 
 Both classes implement the :meth:`ServerAPI._transport` callable shape —
 ``(url, body=None, headers=None) -> bytes``, raising the same exception
-taxonomy as the real urllib hop — so they slot under the genuine
+classes as the real urllib hop — so they slot under the genuine
 retry/classification/circuit-breaker stack rather than around it.
 """
 

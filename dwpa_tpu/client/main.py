@@ -297,12 +297,13 @@ class TpuCrackClient:
         # Degraded-mode unit buffer (_prefetch_units): units leased ahead
         # while the transport is healthy, cracked while it is OPEN.
         self._unit_buffer = []
-        # Cold-start: persist XLA compilations under the workdir so a
-        # restarted client skips the ~20-40 s PBKDF2 compile (SURVEY §5.4
-        # resume latency; tracked by bench.py unit_overhead).
+        # Cold-start: persist XLA compilations (JAX_COMPILATION_CACHE_DIR,
+        # else the fixed <repo>/.xla_cache — never under the workdir, which
+        # moves with the cwd) so a restarted client skips the PBKDF2
+        # compile (SURVEY §5.4 resume latency).
         from ..utils.compcache import enable_compilation_cache
 
-        enable_compilation_cache(os.path.join(config.workdir, "xla_cache"))
+        enable_compilation_cache()
         # Persistent PMK store (optional): repeat (ESSID, word) pairs —
         # popular ESSIDs across uploads, overlapping dicts, pass-2
         # replays of pass-1 words — become disk hits instead of PBKDF2.
@@ -449,6 +450,12 @@ class TpuCrackClient:
         trace is shared regardless.  With the persistent cache (see
         __init__) the compile happens once per installation; afterwards
         this is ~0.2 s of device work.
+
+        The fused device-rules step of pass 2 is not warmed: its program
+        is keyed on the unit's own net partition, which a synthetic warm
+        group rarely matches, so a warm compile here is rarely reused (a
+        unit with rules compiles its group's step once, over a minute on
+        a v5e host — chip run, PR 21).
         """
         # perf_counter, not time.time(): an NTP step mid-prewarm must not
         # corrupt the logged duration (same rule as the pacing clock)
@@ -486,29 +493,8 @@ class TpuCrackClient:
             self._crack_blocks(eng, feed)
         finally:
             feed.close()
-        if jax.process_count() == 1:
-            # Pass 2 runs through the fused device-rules step now; warm
-            # both interpreter step buckets so a first unit carrying
-            # server rules doesn't stall on the fused-step compile —
-            # through the SAME blocks/streams entry the real pass-2
-            # takes, so streams mode warms the 1-device rules step on
-            # every chip, not the full-mesh shape it will never run.
-            from ..feed.framing import frame_blocks
-            from ..rules import parse_rules
-
-            wrules = parse_rules([":", "c $1 $2"])
-            wblocks = frame_blocks(
-                (b"warm-%08d" % i for i in range(n)), n)
-            if self._use_streams():
-                eng.crack_rules_streams(wblocks, wrules,
-                                        registry=self.registry,
-                                        tracer=self.tracer)
-            else:
-                eng.crack_rules_blocks(wblocks, wrules,
-                                       registry=self.registry,
-                                       tracer=self.tracer)
-        # crack_batch/crack_rules sync internally (hits gate), so the
-        # span's clock stops after real device completion
+        # crack_blocks syncs internally (hits gate), so the span's clock
+        # stops after real device completion
         sp.stop()
         self.log(f"prewarm: work-size steps ready in {sp.seconds:.1f}s")
 

@@ -56,6 +56,7 @@ costs a few wasted PBKDF2s, never a false accept.
 import hashlib
 import re
 
+from ..utils.device import on_tpu
 from .imei import imei_candidates
 
 # ---------------------------------------------------------------------------
@@ -92,11 +93,7 @@ def thomson_candidates(ssid_suffix: str, years=range(4, 13), weeks=range(1, 54),
     """
     target = ssid_suffix.upper()
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0].platform == "tpu"
-        except Exception:  # pragma: no cover - jax is a hard dep in-tree
-            device = False
+        device = on_tpu()
     if device:
         yield from _thomson_search_device(target, list(years), list(weeks))
         return
@@ -130,8 +127,7 @@ def _thomson_search_device(target: str, years, weeks, chunk: int = 1 << 20,
     if compress is None:
         # The unrolled form is fastest on TPU; XLA:CPU takes minutes to
         # compile 80 straight-line rounds, so fall back to the rolled one.
-        on_tpu = jax.devices()[0].platform == "tpu"
-        compress = sha1_compress if on_tpu else sha1_compress_rolled
+        compress = sha1_compress if on_tpu() else sha1_compress_rolled
 
     yw = [(yy, ww) for yy in years for ww in weeks]
     ncodes = 36 ** 3
@@ -479,12 +475,7 @@ def vendor_candidates(bssid: bytes, ssid: bytes, thomson_kw=None,
         # job bounded on CPU-only server hosts.
         kw = thomson_kw
         if kw is None:
-            try:
-                import jax
-                on_acc = jax.devices()[0].platform == "tpu"
-            except Exception:  # pragma: no cover
-                on_acc = False
-            kw = {} if on_acc else None
+            kw = {} if on_tpu() else None
         if kw is not None:
             for key in thomson_candidates(m.group(2).decode(), **kw):
                 yield ("Thomson", key)

@@ -12,22 +12,11 @@ requirement.
 import ctypes
 import os
 import subprocess
+import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "capture_fast.cpp")
 _SO = os.path.join(_DIR, "capture_fast.so")
-
-
-def build(force: bool = False) -> str:
-    """Compile capture_fast.so if missing/stale; returns the .so path."""
-    if (not force and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        return _SO
-    subprocess.run(
-        ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
-        check=True, capture_output=True,
-    )
-    return _SO
 
 
 def _configure_capture(lib):
@@ -85,6 +74,7 @@ _PACK_SO = os.path.join(_DIR, "pack_fast.so")
 #: src path -> ctypes lib | None (None = build/load failed; cached so the
 #: per-batch hot path never re-attempts a doomed g++ run)
 _LIBS = {}
+_LIBS_LOCK = threading.Lock()
 
 
 def _load_lib(src: str, so: str, configure, auto_build: bool = True):
@@ -93,24 +83,31 @@ def _load_lib(src: str, so: str, configure, auto_build: bool = True):
     ``configure(lib)`` sets restype/argtypes.  Failures are cached as
     None — callers on hot paths fall back to Python exactly once.
     """
-    if src in _LIBS:
-        return _LIBS[src]
-    lib = None
-    try:
-        if auto_build and not (
-            os.path.exists(so)
-            and os.path.getmtime(so) >= os.path.getmtime(src)
-        ):
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-o", so, src],
-                check=True, capture_output=True,
-            )
-        lib = ctypes.CDLL(so)
-        configure(lib)
-    except (OSError, subprocess.CalledProcessError):
+    with _LIBS_LOCK:
+        if src in _LIBS:
+            return _LIBS[src]
         lib = None
-    _LIBS[src] = lib
-    return lib
+        try:
+            if auto_build and not (
+                os.path.exists(so)
+                and os.path.getmtime(so) >= os.path.getmtime(src)
+            ):
+                # Build under a private name and rename into place: feed
+                # producer threads and test workers may all reach a
+                # fresh checkout's first build at once, and a loader
+                # must never map another builder's half-written file.
+                tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
+                subprocess.run(
+                    ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, src],
+                    check=True, capture_output=True,
+                )
+                os.replace(tmp, so)
+            lib = ctypes.CDLL(so)
+            configure(lib)
+        except (OSError, subprocess.CalledProcessError):
+            lib = None
+        _LIBS[src] = lib
+        return lib
 
 
 def _configure_pack(lib):

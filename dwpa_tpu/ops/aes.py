@@ -2,13 +2,20 @@
 
 Needed only for the WPA2 802.11w keyver=3 MIC (AES-128-CMAC over the EAPOL
 frame, reference semantics: web/common.php:272 / omac1_aes_128 at
-web/common.php:56-112).  keyver=3 nets are rare, so this path favours
-clarity over raw speed: the state is 16 per-byte uint32 arrays and SubBytes
-is a 256-entry ``jnp.take`` (TPU handles the gather; the cost is dwarfed by
-the PBKDF2 loop that precedes it).
+web/common.php:56-112).  The state is 16 per-byte uint32 arrays.
 
-The S-box is generated from the GF(2^8) definition at import time rather
-than transcribed, and checked by FIPS-197 test vectors in the test suite.
+SubBytes is computed, not looked up: the Boyar-Peralta S-box circuit (a
+fixed network of XOR/AND gates over the byte's bit planes), evaluated on
+four bytes per uint32 word where the array shape allows.  A 256-entry
+``jnp.take`` gathers one element at a time on the TPU: on a v5e one
+keyver-3 net's NC-8 verify at B=131,072 then took tens of seconds per
+batch, far longer than the PBKDF2 it follows (chip run, PR 21).  The
+circuit is plain element-wise uint32 work, which the VPU runs at full
+width.
+
+The S-box table is generated from the GF(2^8) definition at import time
+(rather than transcribed) as the reference the circuit is tested against,
+exhaustively; FIPS-197 vectors check the cipher as a whole.
 """
 
 import numpy as np
@@ -55,10 +62,107 @@ SBOX = _make_sbox()
 RCON = [1, 2, 4, 8, 16, 32, 64, 128, 27, 54]
 
 
+# Boyar & Peralta, "A depth-16 circuit for the AES S-box" (2011): input
+# bits U0 (MSB) .. U7, a linear top layer, a non-linear middle and a
+# linear bottom; outputs S0 (MSB) .. S7, four of them complemented.
+_BP_TOP = (
+    ("T1", "U0", "U3"), ("T2", "U0", "U5"), ("T3", "U0", "U6"),
+    ("T4", "U3", "U5"), ("T5", "U4", "U6"), ("T6", "T1", "T5"),
+    ("T7", "U1", "U2"), ("T8", "U7", "T6"), ("T9", "U7", "T7"),
+    ("T10", "T6", "T7"), ("T11", "U1", "U5"), ("T12", "U2", "U5"),
+    ("T13", "T3", "T4"), ("T14", "T6", "T11"), ("T15", "T5", "T11"),
+    ("T16", "T5", "T12"), ("T17", "T9", "T16"), ("T18", "U3", "U7"),
+    ("T19", "T7", "T18"), ("T20", "T1", "T19"), ("T21", "U6", "U7"),
+    ("T22", "T7", "T21"), ("T23", "T2", "T22"), ("T24", "T2", "T10"),
+    ("T25", "T20", "T17"), ("T26", "T3", "T16"), ("T27", "T1", "T12"),
+)
+_BP_MID = (  # (out, op, a, b): op "&" is AND, "^" is XOR
+    ("M1", "&", "T13", "T6"), ("M2", "&", "T23", "T8"),
+    ("M3", "^", "T14", "M1"), ("M4", "&", "T19", "U7"),
+    ("M5", "^", "M4", "M1"), ("M6", "&", "T3", "T16"),
+    ("M7", "&", "T22", "T9"), ("M8", "^", "T26", "M6"),
+    ("M9", "&", "T20", "T17"), ("M10", "^", "M9", "M6"),
+    ("M11", "&", "T1", "T15"), ("M12", "&", "T4", "T27"),
+    ("M13", "^", "M12", "M11"), ("M14", "&", "T2", "T10"),
+    ("M15", "^", "M14", "M11"), ("M16", "^", "M3", "M2"),
+    ("M17", "^", "M5", "T24"), ("M18", "^", "M8", "M7"),
+    ("M19", "^", "M10", "M15"), ("M20", "^", "M16", "M13"),
+    ("M21", "^", "M17", "M15"), ("M22", "^", "M18", "M13"),
+    ("M23", "^", "M19", "T25"), ("M24", "^", "M22", "M23"),
+    ("M25", "&", "M22", "M20"), ("M26", "^", "M21", "M25"),
+    ("M27", "^", "M20", "M21"), ("M28", "^", "M23", "M25"),
+    ("M29", "&", "M28", "M27"), ("M30", "&", "M26", "M24"),
+    ("M31", "&", "M20", "M23"), ("M32", "&", "M27", "M31"),
+    ("M33", "^", "M27", "M25"), ("M34", "&", "M21", "M22"),
+    ("M35", "&", "M24", "M34"), ("M36", "^", "M24", "M25"),
+    ("M37", "^", "M21", "M29"), ("M38", "^", "M32", "M33"),
+    ("M39", "^", "M23", "M30"), ("M40", "^", "M35", "M36"),
+    ("M41", "^", "M38", "M40"), ("M42", "^", "M37", "M39"),
+    ("M43", "^", "M37", "M38"), ("M44", "^", "M39", "M40"),
+    ("M45", "^", "M42", "M41"), ("M46", "&", "M44", "T6"),
+    ("M47", "&", "M40", "T8"), ("M48", "&", "M39", "U7"),
+    ("M49", "&", "M43", "T16"), ("M50", "&", "M38", "T9"),
+    ("M51", "&", "M37", "T17"), ("M52", "&", "M42", "T15"),
+    ("M53", "&", "M45", "T27"), ("M54", "&", "M41", "T10"),
+    ("M55", "&", "M44", "T13"), ("M56", "&", "M40", "T23"),
+    ("M57", "&", "M39", "T19"), ("M58", "&", "M43", "T3"),
+    ("M59", "&", "M38", "T22"), ("M60", "&", "M37", "T20"),
+    ("M61", "&", "M42", "T1"), ("M62", "&", "M45", "T4"),
+    ("M63", "&", "M41", "T2"),
+)
+_BP_BOT = (
+    ("L0", "M61", "M62"), ("L1", "M50", "M56"), ("L2", "M46", "M48"),
+    ("L3", "M47", "M55"), ("L4", "M54", "M58"), ("L5", "M49", "M61"),
+    ("L6", "M62", "L5"), ("L7", "M46", "L3"), ("L8", "M51", "M59"),
+    ("L9", "M52", "M53"), ("L10", "M53", "L4"), ("L11", "M60", "L2"),
+    ("L12", "M48", "M51"), ("L13", "M50", "L0"), ("L14", "M52", "M61"),
+    ("L15", "M55", "L1"), ("L16", "M56", "L0"), ("L17", "M57", "L1"),
+    ("L18", "M58", "L8"), ("L19", "M63", "L4"), ("L20", "L0", "L1"),
+    ("L21", "L1", "L7"), ("L22", "L3", "L12"), ("L23", "L18", "L2"),
+    ("L24", "L15", "L9"), ("L25", "L6", "L10"), ("L26", "L7", "L9"),
+    ("L27", "L8", "L10"), ("L28", "L11", "L14"), ("L29", "L11", "L17"),
+)
+_BP_OUT = (  # S0 .. S7: (a, b, complemented)
+    ("L6", "L24", False), ("L16", "L26", True), ("L19", "L28", True),
+    ("L6", "L21", False), ("L20", "L22", False), ("L25", "L29", False),
+    ("L13", "L27", True), ("L6", "L23", True),
+)
+
+
+def _sbox_planes(x, one):
+    """The S-box of every byte lane of ``x`` at once.  ``one`` marks bit
+    0 of each lane: 1 for one byte per word, 0x01010101 for four."""
+    v = {f"U{i}": (x >> (7 - i)) & one for i in range(8)}
+    for out, a, b in _BP_TOP:
+        v[out] = v[a] ^ v[b]
+    for out, op, a, b in _BP_MID:
+        v[out] = (v[a] & v[b]) if op == "&" else (v[a] ^ v[b])
+    for out, a, b in _BP_BOT:
+        v[out] = v[a] ^ v[b]
+    y = None
+    for i, (a, b, inv) in enumerate(_BP_OUT):
+        s = v[a] ^ v[b]
+        if inv:
+            s = s ^ one
+        s = s << (7 - i)
+        y = s if y is None else y | s
+    return y
+
+
 def _sub(byte_arr):
-    # jnp.asarray of a host constant folds to an XLA constant per trace;
-    # caching the device array globally would leak tracers across traces.
-    return jnp.take(jnp.asarray(SBOX), byte_arr.astype(jnp.int32))
+    """SubBytes over uint32 byte values (see the module docstring).
+
+    When the leading axis holds a multiple of four bytes (the rolled
+    state's 16, the key schedule's 4), four bytes share a word so each
+    gate of the circuit serves four S-boxes."""
+    x = u32(byte_arr)
+    if x.ndim == 0 or x.shape[0] % 4:
+        return _sbox_planes(x, u32(1))
+    q = x.reshape((x.shape[0] // 4, 4) + x.shape[1:])
+    w = q[:, 0] | (q[:, 1] << 8) | (q[:, 2] << 16) | (q[:, 3] << 24)
+    y = _sbox_planes(w, u32(0x01010101))
+    return jnp.stack([(y >> s) & u32(0xFF) for s in (0, 8, 16, 24)],
+                     axis=1).reshape(x.shape)
 
 
 def _xtime(b):
@@ -114,7 +218,8 @@ def aes128_encrypt_block(round_keys, block16):
 # ---------------------------------------------------------------------------
 # Rolled array-state variant (cold-path compile-time trade, like
 # sha1_compress_rolled): state is ONE uint32[16, ...] array, rounds are a
-# fori_loop, SubBytes one gather, ShiftRows a constant permutation.
+# fori_loop, SubBytes the gate circuit (see the module docstring),
+# ShiftRows a constant permutation.
 # ---------------------------------------------------------------------------
 
 import jax

@@ -36,6 +36,7 @@ from .hmac import (
     hmac_sha1_blocks,
     hmac_sha1_precompute,
 )
+from .sha1 import sha1_compress_rolled
 
 # Lane-tile sublane count per Pallas program.  (TILE, 128) uint32 words;
 # TILE=32 -> 4 vregs per word -> 4-way independent chains per VPU op.
@@ -82,7 +83,7 @@ def _loop_kernel(iterations, unroll, hoist, sin_ref, out_ref):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "iterations", "tile", "unroll", "interpret", "prologue_compress", "hoist",
+        "iterations", "tile", "unroll", "interpret", "hoist",
     ),
 )
 def pbkdf2_sha1_pmk_pallas(
@@ -94,7 +95,6 @@ def pbkdf2_sha1_pmk_pallas(
     tile=DEFAULT_TILE,
     unroll=1,
     interpret=False,
-    prologue_compress=None,
     hoist=True,
 ):
     """Derive 32-byte PMKs for a packed password batch on TPU via Pallas.
@@ -123,12 +123,12 @@ def pbkdf2_sha1_pmk_pallas(
     if interpret:
         hoist = False
 
-    # Cold prologue (5 compressions of the 8192): pad states + U1, XLA-side.
-    # ``prologue_compress`` lets CPU callers (tests) use the rolled
-    # compression, whose XLA:CPU compile is seconds rather than minutes.
-    kw = {}
-    if prologue_compress is not None:
-        kw = {"compress": prologue_compress}
+    # Cold prologue (5 compressions of the 8192): pad states + U1, XLA-side,
+    # with the ROLLED compression.  Its run time is noise next to the
+    # 8187 in-kernel compressions, while the unrolled form made every
+    # step that embeds this kernel compile for ~20-30 s more on the TPU
+    # compiler (v5e AOT, PR 21) — the bulk of a cold start.
+    kw = {"compress": sha1_compress_rolled}
     ist, ost = hmac_sha1_precompute(pw, **kw)
     if salt1.ndim == 2:
         # Per-lane salts: word i of lane b's first-iteration message is

@@ -90,27 +90,6 @@ def multihost_mesh(coordinator: str = None, num_processes: int = None,
     # anything touches the XLA backend (even jax.process_count() would
     # initialise it), hence the check against the distributed-service
     # state rather than device APIs.
-    if (auto_init or kw) and not _distributed_initialized():
-        try:
-            # Multi-process computations on the CPU backend need an
-            # explicit collectives implementation on the jax 0.4/0.5
-            # line (later versions default to gloo); harmless on TPU,
-            # where collectives ride ICI/DCN regardless.  Must be set
-            # before the backend initializes, i.e. exactly here.
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # flag retired upstream
-            pass
+    if (auto_init or kw) and not jax.distributed.is_initialized():
         jax.distributed.initialize(**kw)
     return Mesh(np.asarray(jax.devices()), (DP_AXIS,))
-
-
-def _distributed_initialized() -> bool:
-    """jax.distributed.is_initialized arrived after the 0.4 line; fall
-    back to the distributed-service client state it reads (still no
-    device APIs — touching those would initialise the XLA backend and
-    break the init-ordering contract above)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    from jax._src.distributed import global_state
-
-    return global_state.client is not None

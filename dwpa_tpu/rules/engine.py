@@ -134,7 +134,7 @@ def apply_rules(rules, words, workers: int = 0, force_pool: bool = False):
     host has cores to spare.  On a host with fewer than ``workers + 1``
     cores the pool contends with the feeding process and measures
     *slower* than serial (2-core container: 769k pooled vs 995k serial,
-    BENCH_r03 host_feed), so ``--rule-workers`` is auto-ignored there
+    a host-clock measurement of round 3), so ``--rule-workers`` is auto-ignored there
     with a warning; ``force_pool`` overrides the guard (benchmarks use
     it to keep tracking the true pooled rate).
     """

@@ -1,7 +1,8 @@
 """Mixed-ESSID batch fusion: pack several small work units into one
 full device batch (pure host work).
 
-BENCH_r05's ~25x steady-vs-small-unit gap is structural: the scalar-salt
+The ~25x steady-vs-small-unit gap of the last pre-growth chip record
+(an earlier remote setup, since deleted) is structural: the scalar-salt
 PMK step takes ONE ESSID per dispatch, so every small ESSID-group x dict
 unit pads its ~1k candidates up to the compiled batch width and runs
 alone — per-unit fixed costs and dead padding lanes bound aggregate

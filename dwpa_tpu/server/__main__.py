@@ -16,8 +16,11 @@ point covers them:
 import argparse
 import json
 import re
+import socketserver
 import sys
+import threading
 import time
+from wsgiref.simple_server import WSGIServer
 
 
 def _load_conf(args):
@@ -68,56 +71,53 @@ def _core(args):
     return core
 
 
+class ThreadingWSGIServer(socketserver.ThreadingMixIn, WSGIServer):
+    """One thread per request, like the reference under Apache
+    prefork: a slow capture upload must not block get_work for the
+    whole fleet.  Database serializes statements; get_work holds the
+    scheduler mutex (core.py).  Concurrent request handling is
+    capped (Apache's MaxClients analog) so N hostile uploads cannot
+    hold N x 64 MiB request bodies in memory at once — excess
+    connections queue on the semaphore.
+    """
+
+    daemon_threads = True
+    max_concurrent = 16
+    request_timeout = 120.0  # reference client's socket timeout
+
+    def process_request(self, request, client_address):
+        # Acquire in the accept loop, BEFORE spawning the handler
+        # thread: resources (threads, fds, bodies) are bounded at
+        # the accept layer; excess connections wait in the kernel
+        # listen backlog, exactly like Apache at MaxClients.
+        self._request_slots.acquire()
+        try:
+            super().process_request(request, client_address)
+        except Exception:
+            self._request_slots.release()
+            raise
+
+    def process_request_thread(self, request, client_address):
+        try:
+            # An idle/stalled peer must not hold its slot forever —
+            # reads time out, the handler errors, the slot frees.
+            request.settimeout(self.request_timeout)
+            super().process_request_thread(request, client_address)
+        finally:
+            self._request_slots.release()
+
+    def server_activate(self):
+        self._request_slots = threading.BoundedSemaphore(
+            self.max_concurrent
+        )
+        super().server_activate()
+
+
 def cmd_serve(args):
-    import socketserver
-    from wsgiref.simple_server import WSGIServer, make_server
-
-    from .api import make_wsgi_app
-
-    class ThreadingWSGIServer(socketserver.ThreadingMixIn, WSGIServer):
-        """One thread per request, like the reference under Apache
-        prefork: a slow capture upload must not block get_work for the
-        whole fleet.  Database serializes statements; get_work holds the
-        scheduler mutex (core.py).  Concurrent request handling is
-        capped (Apache's MaxClients analog) so N hostile uploads cannot
-        hold N x 64 MiB request bodies in memory at once — excess
-        connections queue on the semaphore.
-        """
-
-        daemon_threads = True
-        max_concurrent = 16
-        request_timeout = 120.0  # reference client's socket timeout
-
-        def process_request(self, request, client_address):
-            # Acquire in the accept loop, BEFORE spawning the handler
-            # thread: resources (threads, fds, bodies) are bounded at
-            # the accept layer; excess connections wait in the kernel
-            # listen backlog, exactly like Apache at MaxClients.
-            self._request_slots.acquire()
-            try:
-                super().process_request(request, client_address)
-            except Exception:
-                self._request_slots.release()
-                raise
-
-        def process_request_thread(self, request, client_address):
-            try:
-                # An idle/stalled peer must not hold its slot forever —
-                # reads time out, the handler errors, the slot frees.
-                request.settimeout(self.request_timeout)
-                super().process_request_thread(request, client_address)
-            finally:
-                self._request_slots.release()
-
-        def server_activate(self):
-            import threading
-
-            self._request_slots = threading.BoundedSemaphore(
-                self.max_concurrent
-            )
-            super().server_activate()
+    from wsgiref.simple_server import make_server
 
     from ..obs import setup_logging
+    from .api import make_wsgi_app
 
     setup_logging()
     serve_core = _core(args)
@@ -136,8 +136,6 @@ def cmd_serve(args):
     if getattr(args, "with_jobs", False):
         # The cron layer in-process: its own ServerCore (sqlite handles
         # are not shared across threads; WAL serializes the writers).
-        import threading
-
         if args.db == ":memory:":
             raise SystemExit("--with-jobs needs a file-backed --db "
                              "(a second :memory: handle would be empty)")
@@ -170,7 +168,6 @@ def _start_materializer(core, interval: float = 1.0):
     within one tick and the thread can then be joined — the thread-
     lifecycle rule every spawn in this repo follows (daemon=True is the
     backstop for serve_forever's hard exit, not the shutdown story)."""
-    import threading
 
     if core.queue is None:
         return None

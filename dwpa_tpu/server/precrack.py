@@ -48,24 +48,13 @@ import threading
 from ..models import hashline as hl
 from ..obs import SpanTracer
 from ..oracle import m22000 as oracle
+from ..utils.device import on_tpu
 from .db import long2mac
 
 # WPA passphrase bounds (models.m22000.MIN/MAX_PSK_LEN without importing
 # the jax-backed module at server start): only these lengths are
 # device-packable and store-worthy; anything else host-derives.
 _MIN_LEN, _MAX_LEN = 8, 63
-
-
-def _device_available() -> bool:
-    """Device batching is worth it only on a real accelerator — the XLA
-    CPU PBKDF2 lane code loses to OpenSSL's ``hashlib.pbkdf2_hmac`` (the
-    same gate ``gen.vendors`` applies to the Thomson sweep)."""
-    try:
-        import jax
-
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no jax / no devices
-        return False
 
 
 class PmkBatcher:
@@ -104,7 +93,10 @@ class PmkBatcher:
             return False
         if self.device == "on":
             return True
-        return _device_available()
+        # Device batching is worth it only on a real accelerator: the
+        # XLA CPU PBKDF2 lane code loses to OpenSSL's hashlib.  A broken
+        # backend raises here instead of reading as "no accelerator".
+        return on_tpu()
 
     def seed(self, essid: bytes, word: bytes, pmk: bytes):
         """Pre-load a known PMK (e.g. a cracked sibling's stored PMK) so

@@ -19,15 +19,15 @@ def main():
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=4"
         ).strip()
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, REPO)
     import jax
 
     jax.config.update("jax_platforms", "cpu")
 
     from dwpa_tpu.utils.compcache import enable_compilation_cache
 
-    enable_compilation_cache(os.path.join(
-        os.path.dirname(__file__), "..", ".pytest_xla_cache"))
+    enable_compilation_cache(os.path.join(REPO, ".pytest_xla_cache"))
 
     from dwpa_tpu.parallel.mesh import multihost_mesh
 

@@ -16,7 +16,8 @@ def main():
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=4"
         ).strip()
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, REPO)
     import jax
 
     jax.config.update("jax_platforms", "cpu")
@@ -27,8 +28,7 @@ def main():
     # identical run-to-run and dominates this worker's wall clock.
     from dwpa_tpu.utils.compcache import enable_compilation_cache
 
-    enable_compilation_cache(os.path.join(
-        os.path.dirname(__file__), "..", ".pytest_xla_cache"))
+    enable_compilation_cache(os.path.join(REPO, ".pytest_xla_cache"))
 
     from dwpa_tpu import testing as tfx
     from dwpa_tpu.models import hashline as hl

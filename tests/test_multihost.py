@@ -16,27 +16,8 @@ import subprocess
 import sys
 import threading
 
-import jaxlib
-import pytest
-
 WORKER = os.path.join(os.path.dirname(__file__), "mh_worker.py")
 CLIENT_WORKER = os.path.join(os.path.dirname(__file__), "mh_client_worker.py")
-
-# The jax-0.4.37-era CPU gloo transport (jaxlib <= 0.4.37) rejects the
-# shard_map collectives these tests drive with `op.preamble.length <=
-# op.nbytes` (upstream transport bug, fixed in later jaxlib releases).
-# The tests are environment-blocked, not wrong: xfail ONLY on those
-# jaxlibs so tier-1 is deterministic here and the tests re-arm
-# automatically on upgrade.  non-strict: the bug is a transport race,
-# so the processes can occasionally complete anyway.
-_JAXLIB_VER = tuple(int(x) for x in jaxlib.__version__.split(".")[:3])
-GLOO_XFAIL = pytest.mark.xfail(
-    _JAXLIB_VER <= (0, 4, 37),
-    reason=f"jaxlib {jaxlib.__version__} gloo transport bug "
-           "(op.preamble.length <= op.nbytes) breaks two-process "
-           "shard_map collectives on CPU",
-    strict=False,
-)
 
 
 def _free_port() -> int:
@@ -60,7 +41,6 @@ def _communicate_all(procs, timeout):
         raise
 
 
-@GLOO_XFAIL
 def test_two_process_mesh_crack_step():
     port = str(_free_port())
     procs = [
@@ -123,7 +103,6 @@ def test_mixed_version_slice_refuses_to_start(tmp_path):
         assert "mixed client versions" in err, (pid, err[-800:])
 
 
-@GLOO_XFAIL
 def test_two_process_client_single_volunteer(tmp_path):
     """The full CLIENT as one multi-host volunteer: a real socket server
     in this process, two client processes spanning one jax.distributed

@@ -9,6 +9,7 @@ import hashlib
 import hmac as py_hmac
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from dwpa_tpu.ops import aes, hmac, md5, sha1, sha256
@@ -143,6 +144,19 @@ def test_aes128_fips197():
     rks = aes.aes128_expand_key([jnp.uint32(b) for b in key])
     out = aes.aes128_encrypt_block(rks, [jnp.uint32(b) for b in pt])
     assert bytes(int(np.asarray(b)) for b in out) == ct
+
+
+@pytest.mark.parametrize("shape", [(256,), (64, 4), (2, 2, 64)],
+                         ids=["swar_4_per_word", "swar_batched", "one_per_word"])
+def test_aes_sbox_circuit_is_the_table(shape):
+    """SubBytes is the Boyar-Peralta circuit, not a gather: it must equal
+    the GF(2^8)-derived table on every byte, in both the four-bytes-per-
+    word layout and the one-byte-per-word fallback."""
+    x = np.arange(256, dtype=np.uint32).reshape(shape)
+    got = np.asarray(aes._sub(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, aes.SBOX[x])
+    assert all(int(np.asarray(aes._sub(jnp.uint32(v)))) == aes.SBOX[v]
+               for v in (0, 1, 0x53, 0xFF))
 
 
 def test_aes128_cmac_rfc4493():

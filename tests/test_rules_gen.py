@@ -218,7 +218,7 @@ def test_apply_rules_pooled_matches_serial():
 def test_apply_rules_pool_guard_falls_back_serial(monkeypatch, caplog):
     """On a host without spare cores the pool is auto-disabled (with a
     warning) and the serial stream is produced instead — --rule-workers
-    must never make a deployment slower (BENCH_r03 host_feed)."""
+    must never make a deployment slower."""
     import logging
 
     from dwpa_tpu.rules import apply_rules, parse_rules

@@ -82,8 +82,6 @@ def test_pallas_per_lane_prologue_matches_xla():
     reduced iterations (CPU interpret mode)."""
     from dwpa_tpu.ops.pbkdf2 import pbkdf2_sha1_pmk
     from dwpa_tpu.ops.pbkdf2_pallas import pbkdf2_sha1_pmk_pallas
-    from dwpa_tpu.ops.sha1 import sha1_compress_rolled
-
     on_tpu = jax.devices()[0].platform == "tpu"
     pws = [b"fusedpw%03d" % i for i in range(6)]
     lane_essid = [b"PallasNet%d" % (i % 3) for i in range(6)]
@@ -95,8 +93,7 @@ def test_pallas_per_lane_prologue_matches_xla():
         iterations=2)))
     got = np.asarray(pbkdf2_sha1_pmk_pallas(
         rows, jnp.asarray(s1), jnp.asarray(s2), iterations=2, tile=8,
-        interpret=not on_tpu,
-        prologue_compress=None if on_tpu else sha1_compress_rolled))
+        interpret=not on_tpu))
     np.testing.assert_array_equal(got, ref)
 
 
