@@ -390,7 +390,8 @@ class TpuCrackClient:
             synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=2, seed="challenge-e"),
         ]
         with self.tracer.span("challenge"):
-            eng = M22000Engine(lines, nc=self.cfg.nc, batch_size=64)
+            eng = M22000Engine(lines, nc=self.cfg.nc, batch_size=64,
+                               tracer=self.tracer)
             words = [b"notit%04d" % i for i in range(63)] + [CHALLENGE_PSK]
             founds = eng.crack(words)
         ok = len(founds) == 2 and all(f.psk == CHALLENGE_PSK for f in founds)
@@ -471,6 +472,7 @@ class TpuCrackClient:
                                       seed="warm-k3"),
             ],
             nc=self.cfg.nc, batch_size=self.cfg.batch_size,
+            tracer=self.tracer,
         )
         n = eng.batch_size
         # The three width buckets stream through the candidate feed —
@@ -522,10 +524,11 @@ class TpuCrackClient:
         # tmp file's data, resurrecting an older-but-valid checkpoint
         # whose skip count double-counts candidates never re-tried.
         tmp = self.resume_path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(work, f)
-            f.flush()
-        fsync_replace(tmp, self.resume_path)
+        with self.tracer.span("resume:write"):
+            with open(tmp, "w") as f:
+                json.dump(work, f)
+                f.flush()
+            fsync_replace(tmp, self.resume_path)
 
     def _clear_resume(self):
         if os.path.exists(self.resume_path):
@@ -818,10 +821,12 @@ class TpuCrackClient:
 
     def process_work(self, work: dict) -> WorkResult:
         """One work unit, traced end to end: the ``work_unit`` span
-        parents the phase spans (pass1/pass2 here; dict_download and
-        put_work via the bound transport), and the pass PMK/s gauges +
-        recompile counter record inside."""
-        with self.tracer.span("work_unit"):
+        (annotated with the unit's ``hkey[:8]``) parents the phase spans
+        (``engine_init``, pass1/pass2, ``resume:write``, ``archive``,
+        ``founds:record``, ``submit`` here; dict_download and put_work
+        via the bound transport), and the pass PMK/s gauges + recompile
+        counter record inside."""
+        with self.tracer.span("work_unit", unit=work["hkey"][:8]):
             return self._process_work(work)
 
     def _process_work(self, work: dict) -> WorkResult:
@@ -860,12 +865,15 @@ class TpuCrackClient:
             self._m_resume.inc(skip + mask_skip)
         if not self._resuming:
             # once per unit: a resume replay must not duplicate the entry
-            self._archive_work(work)
+            with self.tracer.span("archive"):
+                self._archive_work(work)
         prior_cand = list(progress.get("cand", []))
-        engine = M22000Engine(
-            work["hashes"], nc=self.cfg.nc, batch_size=self.cfg.batch_size,
-            pmk_store=self.pmk_store,
-        )
+        with self.tracer.span("engine_init"):
+            engine = M22000Engine(
+                work["hashes"], nc=self.cfg.nc,
+                batch_size=self.cfg.batch_size, pmk_store=self.pmk_store,
+                tracer=self.tracer,
+            )
         founds = []
         done = skip
         mask_done = mask_skip
@@ -1072,6 +1080,7 @@ class TpuCrackClient:
         elapsed = time.perf_counter() - t0
         st = engine.stage_times
         crack_s = sum(st.values())
+        # The sums of the engine's engine:prepare/dispatch/collect spans.
         # "prepare" is the RESIDUAL on-thread stage time (device staging
         # for feed-prepacked blocks): packing itself runs on the feed's
         # producer threads and is accounted to the feed:produce spans —
@@ -1088,7 +1097,8 @@ class TpuCrackClient:
             candidates_tried=tried,
         )
         if founds:
-            self._record_founds(founds)
+            with self.tracer.span("founds:record"):
+                self._record_founds(founds)
             self._m_founds.inc(len(founds))
         # prior founds from a resumed session are re-submitted: put_work
         # is idempotent server-side and the claim may not have landed
@@ -1096,6 +1106,19 @@ class TpuCrackClient:
             {"k": f.line.mac_ap.hex(), "v": f.psk.hex()} for f in founds
         ]
         cand = [dict(t) for t in {tuple(sorted(c.items())) for c in cand}]
+        # the outbox journal (fsync) and put_work: the unit's submission
+        with self.tracer.span("submit"):
+            result.accepted = self._submit_unit(work, cand)
+        self._m_units.labels(
+            accepted="true" if result.accepted else "false").inc()
+        self._clear_resume()
+        self._autotune(elapsed)
+        return result
+
+    def _submit_unit(self, work: dict, cand: list) -> bool:
+        """Submit one finished unit's founds; returns the server's
+        verdict (False when the transport failed: the founds then wait
+        in the outbox)."""
         if jax.process_count() > 1:
             # One submission per slice: process 0 talks to the server,
             # every host adopts its verdict (all hosts decoded identical
@@ -1116,25 +1139,18 @@ class TpuCrackClient:
             if payload["err"]:
                 raise ConnectionError(
                     f"put_work failed on host 0: {payload['err']}")
-            result.accepted = bool(payload["acc"])
-        else:
-            try:
-                result.accepted = self._submit(work["hkey"], cand,
-                                               epoch=work.get("epoch"))
-            except ConnectionError as e:
-                # Degraded mode: the founds were journaled before the
-                # attempt — delivery now belongs to the outbox drain, so
-                # a dead server costs this unit an "accepted" flag, not
-                # the PSKs and not a parked crack loop.
-                if cand:
-                    self.log(f"put_work failed ({e}); "
-                             f"{len(cand)} found(s) wait in the outbox")
-                result.accepted = False
-        self._m_units.labels(
-            accepted="true" if result.accepted else "false").inc()
-        self._clear_resume()
-        self._autotune(elapsed)
-        return result
+            return bool(payload["acc"])
+        try:
+            return self._submit(work["hkey"], cand, epoch=work.get("epoch"))
+        except ConnectionError as e:
+            # Degraded mode: the founds were journaled before the
+            # attempt — delivery now belongs to the outbox drain, so
+            # a dead server costs this unit an "accepted" flag, not
+            # the PSKs and not a parked crack loop.
+            if cand:
+                self.log(f"put_work failed ({e}); "
+                         f"{len(cand)} found(s) wait in the outbox")
+            return False
 
     def _submit_tries(self) -> int:
         """Transport attempts per submission call.  With the outbox
@@ -1331,11 +1347,13 @@ class TpuCrackClient:
             # outbox drains at startup and between units, and a drain
             # stopped by a transport failure just retries next round.
             try:
-                self._drain_outbox()
+                with self.tracer.span("outbox:drain"):
+                    self._drain_outbox()
             except (ConnectionError, ValueError):
                 pass
             if not multiproc:
-                work = self._read_resume()
+                with self.tracer.span("resume:read"):
+                    work = self._read_resume()
                 if work is None and self._unit_buffer:
                     work = self._unit_buffer.pop(0)
                 if work is None:

@@ -103,17 +103,11 @@ def targeted_candidates(essids, budget: int = 5_000_000):
     families sharing a scheme (netgear/spectrum) stream it once — the
     PBKDF2 is per (candidate, essid) anyway, so one pass of a keyspace
     serves every matching net in the hash file."""
-    from ..obs import default_registry
-
-    matches = default_registry().counter(
-        "dwpa_client_targeted_matches_total",
-        "ESSID-fingerprint family matches streamed in pass 1")
     seen = set()
     for essid in essids:
-        for rx, family, factory in TARGET_TABLE:
+        for rx, _family, factory in TARGET_TABLE:
             m = rx.match(essid)
             if m and factory not in seen:
                 seen.add(factory)
-                matches.labels(family=family).inc()
                 yield from itertools.islice(factory(m, essid), budget)
                 break

@@ -26,8 +26,8 @@ calls for:
   H2D, enqueueing block N+1's upload while block N's steps execute.
 
 Consumed by ``M22000Engine.crack_blocks`` and wired through the client
-(pass 1, both pass-2 paths, prewarm) and ``bench:feed_overlap`` /
-``bench:dict_cache``.
+(pass 1, both pass-2 paths, prewarm) and bench.py's ``feed_overlap`` /
+``dict_cache`` configs.
 """
 
 from .dictcache import DictCache

@@ -24,7 +24,7 @@ Design contracts:
   queue records ``dwpa_feed_producer_stall_seconds``; a consumer
   blocked on an empty one records ``dwpa_feed_consumer_starve_seconds``
   — the starve fraction is the headline "is the host keeping up"
-  number (``bench:feed_overlap`` reports it next to PMK/s).
+  number (bench.py's ``feed_overlap`` reports it next to PMK/s).
 - **Producer thread discipline** (lint rule DW107): producer code runs
   pure host work — framing, byte wrangling, native packing — and may
   touch NO jax device API except ``device_put``/``shard_candidates``.
@@ -41,7 +41,10 @@ Metric names (README "Candidate feed"): ``dwpa_feed_queue_depth``
 ``dwpa_feed_consumer_starve_seconds`` (histograms),
 ``dwpa_feed_blocks_total`` / ``dwpa_feed_candidates_total`` /
 ``dwpa_feed_bytes_total`` (counters) — all labeled ``feed=<name>`` —
-plus ``feed:skip`` / ``feed:produce`` spans in ``dwpa_span_seconds``.
+plus ``feed:skip`` / ``feed:produce`` / ``feed:wait`` spans in
+``dwpa_span_seconds``, each annotated ``feed=<name>`` in the profiler
+trace.  ``feed:wait`` is the consumer's wait, and the starve histogram
+observes its seconds.
 """
 
 import threading
@@ -132,7 +135,8 @@ class CandidateFeed:
         self._fault = None      # FeedError, delivered in stream order
         self._stop = False
         reg = registry or default_registry()
-        self.tracer = SpanTracer(reg)
+        # every span of this feed carries its name in the profiler trace
+        self.tracer = SpanTracer(reg, args={"feed": name})
         lbl = {"feed": name}
         self._m_depth = reg.gauge(
             "dwpa_feed_queue_depth",
@@ -144,7 +148,8 @@ class CandidateFeed:
         ).labels(**lbl)
         self._m_starve = reg.histogram(
             "dwpa_feed_consumer_starve_seconds",
-            "per-block consumer wait on an empty feed queue (host too slow)"
+            "consumer wait on an empty feed queue (host too slow), per "
+            "block and at the stream's end"
         ).labels(**lbl)
         self._m_blocks = reg.counter(
             "dwpa_feed_blocks_total", "candidate blocks through the feed"
@@ -268,16 +273,20 @@ class CandidateFeed:
     def __next__(self):
         if self._inline:
             return self._record(self._next_inline())
-        t0 = time.perf_counter()
+        wait = self.tracer.start("feed:wait")
         with self._cv:
             seq = self._next_get
-            while seq not in self._buf:
-                if self._fault is not None:
-                    raise self._fault
-                if self._end_seq is not None and seq >= self._end_seq:
-                    raise StopIteration
-                self._cv.wait()
-            self._m_starve.observe(time.perf_counter() - t0)
+            try:
+                while seq not in self._buf:
+                    if self._fault is not None:
+                        raise self._fault
+                    if self._end_seq is not None and seq >= self._end_seq:
+                        raise StopIteration
+                    self._cv.wait()
+            finally:
+                # one timing: the span's seconds are the starve sample
+                # (the wait that ends the stream included)
+                self._m_starve.observe(wait.stop())
             blk = self._buf.pop(seq)
             self._next_get = seq + 1
             self._m_depth.set(len(self._buf))

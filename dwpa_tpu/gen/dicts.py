@@ -66,7 +66,7 @@ class DictStream:
 
     #: decompressed bytes per read — one gunzip call amortized over
     #: thousands of lines instead of the line iterator's per-line trips
-    #: through the gzip object (bench: host_feed.dictstream_words_per_s)
+    #: through the gzip object (bench.py: host_feed.dictstream_words_per_s)
     CHUNK = 1 << 18
 
     def __iter__(self):

@@ -29,8 +29,10 @@ TPU-first design:
   fixed-shape uint32 arrays.
 """
 
+import contextlib
+import functools
 import struct
-import time
+import threading
 from dataclasses import dataclass
 
 import jax
@@ -640,6 +642,21 @@ class _Pipeline:
             self.finish_one()
 
 
+def _staged(stage: str):
+    """Run a method as one ``engine:<stage>`` span of the engine's tracer
+    (``M22000Engine._stage``)."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def staged(self, *args, **kwargs):
+            with self._stage(stage):
+                return fn(self, *args, **kwargs)
+
+        return staged
+
+    return deco
+
+
 class M22000Engine:
     """Crack a set of m22000 hashlines with batches of candidate PSKs.
 
@@ -657,7 +674,8 @@ class M22000Engine:
 
     def __init__(self, lines, nc: int = DEFAULT_NC, batch_size: int = 4096,
                  verify_with_oracle: bool = True, mesh="auto",
-                 pmk_store=None):
+                 pmk_store=None, tracer=None):
+        from ..obs.spans import default_tracer
         from ..parallel import default_mesh
 
         if mesh == "auto":
@@ -685,9 +703,13 @@ class M22000Engine:
         self._full = {}   # essid -> original list[PreppedNet]
         self._steps = {}  # essid -> crack step (parallel.build_crack_step)
         self._rules_steps = {}  # essid -> fused rules step (build_rules_step)
-        # Per-stage wall-clock accumulators (SURVEY.md §5.1): host pack +
-        # H2D enqueue / device dispatch / sync + decode.  "collect" is
-        # where device compute surfaces under the async runtime.
+        # Per-stage wall-clock sums (SURVEY.md §5.1) of the engine's
+        # ``engine:prepare`` (host pack + H2D enqueue), ``engine:dispatch``
+        # (step launch: host time to enqueue, compiles included) and
+        # ``engine:collect`` (sync + decode) spans on ``tracer`` (the
+        # client passes its own).  "collect" is where device compute
+        # surfaces under the async runtime; the device's own time is in
+        # the profiler's device trace, not here.
         # Keys are API (the client's stage log and tests read them).
         # Since the candidate feed (dwpa_tpu/feed) moved packing onto
         # producer threads, "prepare" counts only the RESIDUAL on-thread
@@ -696,6 +718,8 @@ class M22000Engine:
         # feed's ``feed:produce`` spans instead, so the two are never
         # double-counted.
         self.stage_times = {"prepare": 0.0, "dispatch": 0.0, "collect": 0.0}
+        self._stage_lock = threading.Lock()
+        self.tracer = tracer or default_tracer()
         for line in lines:
             try:
                 h = line if isinstance(line, hl.Hashline) else hl.parse(line)
@@ -710,6 +734,17 @@ class M22000Engine:
     @property
     def nets(self):
         return [n for group in self.groups.values() for n in group]
+
+    @contextlib.contextmanager
+    def _stage(self, stage: str):
+        """One ``engine:<stage>`` span, whose seconds ``stage_times``
+        sums.  These are host spans: ``engine:dispatch`` is the time to
+        enqueue the steps (and to compile a new shape), never the
+        device's time, which the profiler's device trace holds."""
+        with self.tracer.span("engine:" + stage) as sp:
+            yield sp
+        with self._stage_lock:
+            self.stage_times[stage] += sp.seconds
 
     def remove(self, found: Found):
         """Drop a cracked net (and empty groups) from further batches."""
@@ -748,6 +783,7 @@ class M22000Engine:
             self._rules_steps[essid] = step
         return step
 
+    @_staged("prepare")
     def _prepare(self, passwords):
         """Host stage: decode, filter, pad, pack, and start the async H2D.
 
@@ -758,13 +794,12 @@ class M22000Engine:
         """
         from ..parallel import shard_candidates
 
-        t0 = time.perf_counter()
         plist = passwords if isinstance(passwords, list) else list(passwords)
         if not plist:
             # Multi-process: an empty local block must still dispatch
             # padding or the peers' shard_map collectives hang (see
             # _padding_prep; returns None single-process).
-            return self._padding_prep(t0)
+            return self._padding_prep()
         # Pad to batch_size (or, for an oversize caller-supplied batch, up
         # to the next mesh-size multiple so the shard_map split stays even).
         cap = max(self.batch_size,
@@ -780,7 +815,7 @@ class M22000Engine:
         if fast is not None:
             packed, lens, nvalid = fast
             if nvalid == 0:
-                return self._padding_prep(t0)
+                return self._padding_prep()
             # Size the device batch from the post-filter count, exactly
             # like the fallback: an oversize batch full of invalid words
             # must not inflate the shape (extra zero-row PBKDF2s and a
@@ -791,7 +826,6 @@ class M22000Engine:
             pw_words = shard_candidates(
                 self.mesh, np.ascontiguousarray(packed[:target, :w])
             )
-            self.stage_times["prepare"] += time.perf_counter() - t0
             return _PackedWords(packed, lens), nvalid, pw_words
 
         # $HEX[...] notation decodes to raw bytes before hashing, matching
@@ -799,7 +833,7 @@ class M22000Engine:
         pws = [oracle.hc_unhex(p) for p in plist]
         pws = [p for p in pws if MIN_PSK_LEN <= len(p) <= MAX_PSK_LEN]
         if not pws:
-            return self._padding_prep(t0)
+            return self._padding_prep()
         nvalid = len(pws)
         target = max(self.batch_size, -(-nvalid // self.mesh.size) * self.mesh.size)
         w = _trim_cols(max(len(p) for p in pws))
@@ -808,7 +842,6 @@ class M22000Engine:
         pw_words = shard_candidates(
             self.mesh, np.ascontiguousarray(bo.pack_passwords_be(pws)[:, :w])
         )
-        self.stage_times["prepare"] += time.perf_counter() - t0
         return pws, nvalid, pw_words
 
     def host_packer(self):
@@ -868,6 +901,7 @@ class M22000Engine:
         pack.supports_pre = True
         return pack
 
+    @_staged("prepare")
     def _prepare_staged(self, packed, lens, nvalid):
         """Consumer-side residual of ``_prepare`` for a feed-prepacked
         block: only the device staging (column trim + async H2D) — the
@@ -877,16 +911,14 @@ class M22000Engine:
         """
         from ..parallel import shard_candidates
 
-        t0 = time.perf_counter()
         if nvalid == 0:
-            return self._padding_prep(t0)
+            return self._padding_prep()
         target = max(self.batch_size,
                      -(-nvalid // self.mesh.size) * self.mesh.size)
         w = _trim_cols(int(lens.max()))
         pw_words = shard_candidates(
             self.mesh, np.ascontiguousarray(packed[:target, :w])
         )
-        self.stage_times["prepare"] += time.perf_counter() - t0
         return _PackedWords(packed, lens), nvalid, pw_words
 
     def _prepare_block(self, block):
@@ -909,12 +941,11 @@ class M22000Engine:
 
             n = block.count
             gen = -(-n // self.mesh.size) * self.mesh.size
-            t0 = time.perf_counter()
-            pw_words = device_mask_words(
-                prep.mask, prep.start, gen, prep.custom,
-                sharding=NamedSharding(self.mesh, P(DP_AXIS, None)),
-            )
-            self.stage_times["prepare"] += time.perf_counter() - t0
+            with self._stage("prepare"):
+                pw_words = device_mask_words(
+                    prep.mask, prep.start, gen, prep.custom,
+                    sharding=NamedSharding(self.mesh, P(DP_AXIS, None)),
+                )
             return _MaskWords(prep.mask, prep.custom, prep.start), n, pw_words
         if hasattr(prep, "materialize"):
             # a lazy dict-cache prep (framing.PackedSlices) normally
@@ -929,6 +960,7 @@ class M22000Engine:
             return self._prepare_mixed(prep)
         return self._prepare_staged(*prep)
 
+    @_staged("prepare")
     def _prepare_mixed(self, mp):
         """Consumer-side staging of a store-split block: start the async
         H2D of each group's compacted miss sub-batch (column-trimmed
@@ -938,16 +970,14 @@ class M22000Engine:
         producer thread and lives in ``feed:produce`` spans."""
         from ..parallel import shard_candidates
 
-        t0 = time.perf_counter()
         for ent in mp.entries.values():
             if ent.nmiss:
                 w = _trim_cols(int(ent.miss_lens.max()))
                 ent.miss_dev = shard_candidates(
                     self.mesh, np.ascontiguousarray(ent.miss_rows[:, :w]))
-        self.stage_times["prepare"] += time.perf_counter() - t0
         return _PackedWords(mp.packed, mp.lens), mp.nvalid, mp
 
-    def _padding_prep(self, t0):
+    def _padding_prep(self):
         """All-padding batch for a shard that contributed no valid words.
 
         On a multi-process mesh every host must enter the shard_map
@@ -967,9 +997,9 @@ class M22000Engine:
             self.mesh, np.zeros((self.batch_size, _trim_cols(MIN_PSK_LEN)),
                                 np.uint32)
         )
-        self.stage_times["prepare"] += time.perf_counter() - t0
         return [], 0, pw_words
 
+    @_staged("dispatch")
     def _dispatch(self, prep):
         """Launch the crack step for every live ESSID group (no host sync).
 
@@ -977,20 +1007,18 @@ class M22000Engine:
         (cracked nets included — their extra MIC checks are noise next to
         the shared PBKDF2); _collect masks the dead rows.
         """
-        t0 = time.perf_counter()
         pws, nvalid, pw_words = prep
         from ..pmkstore.stage import MixedPrep
 
         if isinstance(pw_words, MixedPrep):
-            return self._dispatch_mixed(pws, nvalid, pw_words, t0)
+            return self._dispatch_mixed(pws, nvalid, pw_words)
         outs = []
         for essid in list(self.groups):
             step = self._step_for(essid)
             outs.append((self._full[essid], step(pw_words)))
-        self.stage_times["dispatch"] += time.perf_counter() - t0
         return pws, nvalid, outs
 
-    def _dispatch_mixed(self, pws, nvalid, mp, t0):
+    def _dispatch_mixed(self, pws, nvalid, mp):
         """Mixed hit/miss dispatch (PMK store): per group, PBKDF2 runs
         only on the compacted miss sub-batch, cached PMKs are gathered
         around the computed ones into the full ``uint32[8, B]`` matrix
@@ -1022,7 +1050,6 @@ class M22000Engine:
                 pmk = (pmk_miss if ent.nhit == 0 else
                        mix_step(self.mesh)(pmk_miss, ent.cached, ent.idx))
             outs.append((self._full[essid], step.verify(pmk)))
-        self.stage_times["dispatch"] += time.perf_counter() - t0
         return pws, nvalid, outs, None, writeback
 
     #: Per-host cap on hit columns exchanged in one multi-process batch
@@ -1239,9 +1266,9 @@ class M22000Engine:
                     live.discard(id(net.line))
         return founds
 
+    @_staged("collect")
     def _collect(self, dispatched) -> list:
         """Sync stage: gate on hits, decode founds, prune cracked nets."""
-        t0 = time.perf_counter()
         pws, nvalid, outs = dispatched[:3]
         # Rules records carry the dispatch's per-shard width as a 4th
         # element (see _decode_rules on why it cannot be re-derived);
@@ -1300,8 +1327,14 @@ class M22000Engine:
                 if len(cols) <= self.MAX_FINDS_PER_BATCH:
                     gather = getattr(self, "_pmk_gather_jit", None)
                     if gather is None:
+                        # named for the profile (``jit_find_gather``);
+                        # still one jit per engine, so each engine's
+                        # first find compiles it
+                        def find_gather(p, c):
+                            return p[..., c]
+
                         gather = self._pmk_gather_jit = jax.jit(
-                            lambda p, c: p[..., c])
+                            find_gather)
                     pad = np.zeros(self.MAX_FINDS_PER_BATCH, np.int32)
                     pad[: len(cols)] = cols
                     pmk_cols = np.asarray(gather(pmk_dev, pad))
@@ -1323,7 +1356,6 @@ class M22000Engine:
             for essid, pmk_dev, miss_words, nmiss in writeback:
                 pmk_host = jax.device_get(pmk_dev)
                 self.pmk_store.put(essid, miss_words, pmk_host[:, :nmiss])
-        self.stage_times["collect"] += time.perf_counter() - t0
         return founds
 
     def crack_batch(self, passwords) -> list:
@@ -1474,7 +1506,7 @@ class M22000Engine:
                 lines, nc=self.nc, batch_size=self.batch_size,
                 verify_with_oracle=self.verify_with_oracle,
                 mesh=default_mesh(devices=[device]),
-                pmk_store=self.pmk_store)
+                pmk_store=self.pmk_store, tracer=self.tracer)
 
         ex = StreamExecutor(engine_factory or _default_factory, devices,
                             registry=registry, tracer=tracer,
@@ -1588,6 +1620,7 @@ class M22000Engine:
             finish_one()
         return pipe_founds
 
+    @_staged("dispatch")
     def _dispatch_fused(self, fb):
         """Launch one fused batch (no host sync): ONE per-lane-salt
         PBKDF2 over the compacted miss lanes (``fused_pmk_step`` — the
@@ -1602,7 +1635,6 @@ class M22000Engine:
         from ..parallel.mesh import DP_AXIS, shard_vector
         from ..parallel.step import fused_pmk_step, mix_step
 
-        t0 = time.perf_counter()
         pmk_sharding = getattr(self, "_pmk_sharding", None)
         if pmk_sharding is None:
             pmk_sharding = self._pmk_sharding = NamedSharding(
@@ -1633,9 +1665,9 @@ class M22000Engine:
                 outs.append((u, None, None))
                 continue
             outs.append((u, group, self._step_for(u.key).verify(pmk)))
-        self.stage_times["dispatch"] += time.perf_counter() - t0
         return fb, outs, wb
 
+    @_staged("collect")
     def _collect_fused(self, fb, outs, wb, on_batch, tracer) -> list:
         """Sync + demux one fused batch: gate each unit's verify on its
         hit scalar, mask the found matrix down to the unit's OWN lane
@@ -1645,7 +1677,6 @@ class M22000Engine:
         to the store, and fire ``on_batch`` per unit in layout order."""
         from contextlib import nullcontext
 
-        t0 = time.perf_counter()
         founds = []
         by_unit = {id(u): [] for u, _, _ in outs}
         live = {id(n.line) for g in self.groups.values() for n in g}
@@ -1690,7 +1721,6 @@ class M22000Engine:
         if on_batch is not None:
             for u in fb.units:
                 on_batch(u.key, u.count, by_unit[id(u)])
-        self.stage_times["collect"] += time.perf_counter() - t0
         return founds
 
     def _rules_flush(self, ctx, batch, account, gbatch, nproc, pid,
@@ -1775,31 +1805,30 @@ class M22000Engine:
                     expanded = nplain * len(chunk) - overflow
                     plan.append((chunk, expanded, account(expanded)))
             if any(rep for _, _, rep in plan):
-                t0 = time.perf_counter()
-                # Pad to the engine batch size like _prepare: a distinct
-                # cap per partial batch would mean a fresh multi-second
-                # XLA compile of the fused step per distinct count.
-                cap = max(gbatch,
-                          -(-nplain // self.mesh.size) * self.mesh.size)
-                if pre is not None:
-                    rows = pre.padded_rows(cap)
-                else:
-                    packed = pack_candidates_fast(plain, 0, MAX_PSK_LEN, cap)
-                    if packed is None:  # no native lib: plain Python pack
-                        rows = np.zeros((cap, 16), np.uint32)
-                        rows[:nplain] = bo.pack_passwords_be(plain)
+                with self._stage("prepare"):
+                    # Pad to the engine batch size like _prepare: a distinct
+                    # cap per partial batch would mean a fresh multi-second
+                    # XLA compile of the fused step per distinct count.
+                    cap = max(gbatch,
+                              -(-nplain // self.mesh.size) * self.mesh.size)
+                    if pre is not None:
+                        rows = pre.padded_rows(cap)
                     else:
-                        rows, _, n = packed  # lens_np above is the source
-                        assert n == nplain  # min_len=0: no compaction
-                lens_pad = np.zeros(cap, np.int32)
-                lens_pad[:nplain] = lens_np
-                # Every host packed the identical global batch; ship only
-                # this host's row slice (shard_* assemble the global
-                # array from per-process slices on a multi-process mesh).
-                lo, hi = pid * (cap // nproc), (pid + 1) * (cap // nproc)
-                base_dev = shard_candidates(self.mesh, rows[lo:hi])
-                lens_dev = shard_vector(self.mesh, lens_pad[lo:hi])
-                self.stage_times["prepare"] += time.perf_counter() - t0
+                        packed = pack_candidates_fast(plain, 0, MAX_PSK_LEN, cap)
+                        if packed is None:  # no native lib: plain Python pack
+                            rows = np.zeros((cap, 16), np.uint32)
+                            rows[:nplain] = bo.pack_passwords_be(plain)
+                        else:
+                            rows, _, n = packed  # lens_np above is the source
+                            assert n == nplain  # min_len=0: no compaction
+                    lens_pad = np.zeros(cap, np.int32)
+                    lens_pad[:nplain] = lens_np
+                    # Every host packed the identical global batch; ship only
+                    # this host's row slice (shard_* assemble the global
+                    # array from per-process slices on a multi-process mesh).
+                    lo, hi = pid * (cap // nproc), (pid + 1) * (cap // nproc)
+                    base_dev = shard_candidates(self.mesh, rows[lo:hi])
+                    lens_dev = shard_vector(self.mesh, lens_pad[lo:hi])
         if base_dev is not None:
             # Chunked fused dispatch: each chunk of RULES_CHUNK rules
             # runs expand+PBKDF2+verify in ONE device call per group
@@ -1814,14 +1843,13 @@ class M22000Engine:
                                     ctx.n_steps)
                 pws = [_RuleWords(plain, r) for r, _ in chunk]
                 pws += [None] * (RULES_CHUNK - len(chunk))
-                t0 = time.perf_counter()
                 outs = []
-                for essid in list(self.groups):
-                    step = self._rules_step_for(essid)
-                    outs.append(
-                        (self._full[essid], step(base_dev, lens_dev, stack))
-                    )
-                self.stage_times["dispatch"] += time.perf_counter() - t0
+                with self._stage("dispatch"):
+                    for essid in list(self.groups):
+                        step = self._rules_step_for(essid)
+                        outs.append(
+                            (self._full[essid],
+                             step(base_dev, lens_dev, stack)))
                 ctx.m_device.inc(expanded)
                 push((pws, nplain, outs, cap // self.mesh.size), report)
         # Host-expanded tail: unsupported rules over plain words,
@@ -2074,7 +2102,7 @@ class M22000Engine:
                 lines, nc=self.nc, batch_size=self.batch_size,
                 verify_with_oracle=self.verify_with_oracle,
                 mesh=default_mesh(devices=[device]),
-                pmk_store=self.pmk_store)
+                pmk_store=self.pmk_store, tracer=self.tracer)
 
         inner = engine_factory or _default_factory
 

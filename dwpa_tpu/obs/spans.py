@@ -22,10 +22,17 @@ The DW106 lint rule (analysis/linter.py) enforces this statically on
 the instrumented files, exactly as DW105 does for bench's legacy
 ``perf_counter`` spans.
 
-Timings are recorded twice: into the owning registry as a
-``dwpa_span_seconds{span=...}`` histogram (scrapeable), and into a
-bounded in-memory ring of finished-span records (name, parent, start,
-stop, depth) that tests use to assert well-nestedness.
+Timings are recorded three times: into the owning registry as a
+``dwpa_span_seconds{span=...}`` histogram (scrapeable), into a bounded
+in-memory ring of finished-span records (name, parent, start, stop,
+depth) that tests use to assert well-nestedness, and into the profiler
+trace.  Every span opens a ``jax.profiler.TraceAnnotation`` named
+``dwpa:<name>`` when it starts and closes it after the ``sync=`` fetch,
+so a ``jax.profiler`` capture shows the host's phases on the same clock
+as the device's operations.  Annotation arguments (the tracer's
+``args`` plus each span's keywords) ride along, e.g. ``unit=<hkey[:8]>``
+on ``work_unit`` and ``feed=<name>`` on the feed's spans.  With no trace
+active an annotation costs one native call, so there is no switch.
 """
 
 import contextlib
@@ -46,17 +53,32 @@ def _force_fetch(sync):
     np.asarray(sync)
 
 
+#: prefix of every span's profiler annotation
+ANNOTATION_PREFIX = "dwpa:"
+
+
+def _annotate(name, args):
+    """Open the profiler annotation of span ``name`` (closed by
+    ``Span._close``)."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(ANNOTATION_PREFIX + name, **args)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """One live timing region.  Created by ``SpanTracer.start``/``span``;
     ``seconds`` is valid after ``stop()``."""
 
-    __slots__ = ("tracer", "name", "parent", "depth", "t0", "t1")
+    __slots__ = ("tracer", "name", "parent", "depth", "t0", "t1", "_ann")
 
-    def __init__(self, tracer, name, parent, depth):
+    def __init__(self, tracer, name, parent, depth, args):
         self.tracer = tracer
         self.name = name
         self.parent = parent
         self.depth = depth
+        self._ann = _annotate(name, args)
         self.t0 = time.perf_counter()
         self.t1 = None
 
@@ -80,15 +102,24 @@ class Span:
         self.tracer._finish(self)
         return self.seconds
 
+    def _close(self):
+        """Close the profiler annotation, once."""
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
 
 class SpanTracer:
     """Per-subsystem tracer; records into ``registry`` (default: the
-    process-wide one) and keeps the last ``keep`` finished spans."""
+    process-wide one) and keeps the last ``keep`` finished spans.
+    ``args`` are annotation arguments every span of this tracer carries
+    in the profiler trace."""
 
-    def __init__(self, registry=None, keep: int = 1024):
+    def __init__(self, registry=None, keep: int = 1024, args: dict = None):
         from .metrics import default_registry
 
         self.registry = registry or default_registry()
+        self.args = dict(args or {})
         self._hist = self.registry.histogram(
             "dwpa_span_seconds", "span durations by name")
         self._lock = threading.Lock()
@@ -102,19 +133,27 @@ class SpanTracer:
             st = self._local.stack = []
         return st
 
-    def start(self, name: str) -> Span:
+    def start(self, name: str, **args) -> Span:
+        """Open a span; ``args`` are its annotation's arguments in the
+        profiler trace, on top of the tracer's own."""
         st = self._stack()
         parent = st[-1].name if st else None
-        sp = Span(self, name, parent, len(st))
+        sp = Span(self, name, parent, len(st),
+                  {**self.args, **args} if args else self.args)
         st.append(sp)
         return sp
 
     def _finish(self, sp: Span):
         st = self._stack()
         # pop sp and anything abandoned above it (an exception may have
-        # skipped a child's stop; the stack must never wedge)
+        # skipped a child's stop; the stack must never wedge), closing
+        # the abandoned children's annotations inside sp's own
         if sp in st:
-            del st[st.index(sp):]
+            i = st.index(sp)
+            for child in reversed(st[i + 1:]):
+                child._close()
+            del st[i:]
+        sp._close()
         self._hist.labels(span=sp.name).observe(sp.seconds)
         with self._lock:
             self.finished.append({
@@ -125,11 +164,11 @@ class SpanTracer:
                 del self.finished[: len(self.finished) - self._keep]
 
     @contextlib.contextmanager
-    def span(self, name: str, sync=None):
+    def span(self, name: str, sync=None, **args):
         """Context-managed span.  The body must sync its own device work
         (engine ``crack*`` calls do) or pass ``sync=`` to be fetched at
-        exit — see the module docstring."""
-        sp = self.start(name)
+        exit — see the module docstring.  ``args`` as for ``start``."""
+        sp = self.start(name, **args)
         try:
             yield sp
         finally:

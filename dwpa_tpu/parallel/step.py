@@ -42,7 +42,10 @@ from .mesh import DP_AXIS
 _STEP_CACHE = {}
 
 
-def _shard(mesh, fn, in_specs, out_specs):
+def _shard(mesh, fn, in_specs, out_specs, name):
+    # ``name`` names the jitted program: each step shows under its own
+    # name on a profile's ``XLA Modules`` line (``jit_<name>``).
+    fn.__name__ = fn.__qualname__ = name
     # check_vma=False: the rolled compressions seed their fori_loop
     # carries from unsharded per-net constants, which fails JAX's
     # varying-manual-axes check even though every carry is elementwise over
@@ -70,7 +73,8 @@ def pmk_step(mesh):
             return m._pmk_impl(pw_words, s1, s2, use_pallas=use_pallas)
 
         _STEP_CACHE[key] = _shard(
-            mesh, local, (P(DP_AXIS, None), P(), P()), P(None, DP_AXIS)
+            mesh, local, (P(DP_AXIS, None), P(), P()), P(None, DP_AXIS),
+            "pbkdf2_pmk",
         )
     return _STEP_CACHE[key]
 
@@ -102,6 +106,7 @@ def fused_pmk_step(mesh):
             mesh, local,
             (P(DP_AXIS, None), P(DP_AXIS), P(), P()),
             P(None, DP_AXIS),
+            "pbkdf2_pmk_fused",
         )
     return _STEP_CACHE[key]
 
@@ -146,6 +151,20 @@ _KINDS = {
 }
 
 
+def _scoped(kind, match):
+    """``match`` under the ``verify/<kind>`` named scope, so the verify's
+    ops say in their metadata which verify they belong to."""
+    def scoped(pmk, st, *consts):
+        with jax.named_scope("verify/" + kind):
+            return match(pmk, st, *consts)
+
+    return scoped
+
+
+_KINDS = {kind: (statics, fields, _scoped(kind, match))
+          for kind, (statics, fields, match) in _KINDS.items()}
+
+
 def _kind_of(net) -> str:
     if net.keyver == 100:
         return "pmkid"
@@ -171,6 +190,7 @@ def verify_step(mesh, kind, static):
             local,
             (P(None, DP_AXIS), P()) + (P(),) * len(fields),
             (P(), P(None, None, DP_AXIS)),
+            "verify_" + kind,
         )
     return _STEP_CACHE[key]
 
@@ -217,7 +237,7 @@ def _assemble_step(mesh, struct, v_max, inv):
     key = (mesh, "asm", struct, v_max, None if inv is None else tuple(inv))
     if key not in _STEP_CACHE:
 
-        def assemble(*fnds):
+        def verify_assemble(*fnds):
             rows = []
             for fnd, (n, v) in zip(fnds, struct):
                 fnd = fnd[:n]
@@ -227,7 +247,7 @@ def _assemble_step(mesh, struct, v_max, inv):
             found = rows[0] if len(rows) == 1 else jnp.concatenate(rows)
             return found if inv is None else found[np.asarray(inv)]
 
-        _STEP_CACHE[key] = jax.jit(assemble)
+        _STEP_CACHE[key] = jax.jit(verify_assemble)
     return _STEP_CACHE[key]
 
 
@@ -247,10 +267,12 @@ def mix_step(mesh):
     """
     key = (mesh, "mix")
     if key not in _STEP_CACHE:
+
+        def pmk_mix(pm, cached, idx):
+            return jnp.concatenate([pm, cached], axis=1)[:, idx]
+
         _STEP_CACHE[key] = jax.jit(
-            lambda pm, cached, idx: jnp.concatenate(
-                [pm, cached], axis=1)[:, idx],
-            out_shardings=NamedSharding(mesh, P(None, DP_AXIS)),
+            pmk_mix, out_shardings=NamedSharding(mesh, P(None, DP_AXIS)),
         )
     return _STEP_CACHE[key]
 
@@ -360,6 +382,7 @@ def build_rules_step(mesh, nets, salt1, salt2):
             mesh, local,
             (P(DP_AXIS, None), P(DP_AXIS), P(), P(), P()) + (P(),) * n_specs,
             (P(), P(None, DP_AXIS)),
+            "rules_crack",
         )
     fn = _STEP_CACHE[key]
     flat_consts = tuple(c for p in parts for c in p[3])

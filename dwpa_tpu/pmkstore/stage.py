@@ -14,7 +14,7 @@ Shape discipline: the miss sub-batch is padded to one of at most THREE
 static widths (``miss_widths``: ~B/4, ~B/2, B, rounded up to mesh
 multiples) so the PBKDF2 and mix steps compile a bounded number of
 times however the hit ratio wanders block to block — proven by the
-``recompile_sentinel`` tests and the ``bench:pmkstore`` warm pass.
+``recompile_sentinel`` tests and bench.py's ``pmkstore`` warm pass.
 """
 
 from dataclasses import dataclass, field

@@ -220,7 +220,7 @@ class ServerCore:
         # 0 disables the cap.
         self.max_inflight = MAX_INFLIGHT if max_inflight is None else max_inflight
         # Precomputed issuable-target queue (None = legacy per-request
-        # scheduling scan; bench:server_load compares the two paths).
+        # scheduling scan; bench.py's server_load config compares the two paths).
         self.queue = WorkQueue() if use_queue else None
         self.queue_batch = queue_batch
         # Telemetry sink shared by the WSGI front (api.make_wsgi_app

@@ -250,7 +250,9 @@ def test_metrics_after_one_work_unit(server, tmp_path):
         assert name in by_name, (name, sorted(by_name))
     unit = by_name["work_unit"]
     for name, parent in (("pass1", "work_unit"), ("pass2", "work_unit"),
-                         ("put_work", "work_unit"),
+                         ("submit", "work_unit"),
+                         # put_work is the submission's transport call
+                         ("put_work", "submit"),
                          # the lazy dict fetch fires when pass 2 first
                          # pulls its stream, so it nests under pass2
                          ("dict_download", "pass2")):

@@ -160,9 +160,10 @@ def test_feed_delivers_in_order_with_telemetry():
     assert reg.value("dwpa_feed_candidates_total", feed="t1") == n
     assert reg.value("dwpa_feed_bytes_total", feed="t1") == 7 * n
     assert reg.value("dwpa_feed_queue_depth", feed="t1") is not None
-    # starve histogram: one observation per consumed block
+    # starve histogram: one observation per consumer wait — each
+    # consumed block's, and the one that ends the stream
     assert reg.value("dwpa_feed_consumer_starve_seconds",
-                     feed="t1") == len(blocks)
+                     feed="t1") == len(blocks) + 1
     # producer work landed in feed: spans
     assert reg.value("dwpa_span_seconds", span="feed:produce") == len(blocks)
     assert not _feed_threads()
@@ -362,3 +363,38 @@ def test_stage_times_prepare_is_residual_with_prepack():
     assert reg.value("dwpa_span_seconds", span="feed:produce") == 4
     assert eng.stage_times["prepare"] < eng.stage_times["collect"] + \
         eng.stage_times["dispatch"] + 10  # smoke: keys populated, finite
+
+
+def test_feed_wait_spans_are_the_starve_samples():
+    """The consumer's waits are ``feed:wait`` spans, and the starve
+    histogram observes exactly their seconds: one timing."""
+    reg = MetricsRegistry()
+    feed = CandidateFeed((b"c%06d" % i for i in range(500)), batch_size=32,
+                         registry=reg, name="t2")
+    blocks = list(feed)
+    feed.close()
+    waits = feed.tracer.records("feed:wait")
+    assert len(waits) == len(blocks) + 1
+    assert reg.value("dwpa_feed_consumer_starve_seconds",
+                     feed="t2") == len(waits)
+    snap = reg.snapshot()["dwpa_feed_consumer_starve_seconds"]["samples"]
+    assert sum(s["sum"] for s in snap) == pytest.approx(
+        sum(r["t1"] - r["t0"] for r in waits))
+    assert feed.tracer.args == {"feed": "t2"}
+
+
+def test_stage_times_are_the_engine_span_sums():
+    """``stage_times`` is the per-engine sum of its ``engine:prepare``,
+    ``engine:dispatch`` and ``engine:collect`` spans."""
+    from dwpa_tpu.obs import SpanTracer
+
+    tracer = SpanTracer(MetricsRegistry())
+    eng = M22000Engine(
+        [tfx.make_pmkid_line(b"span-psk-1", b"SpanNet", seed="sp1")],
+        batch_size=64, tracer=tracer)
+    eng.crack((b"word%06d" % i for i in range(64 * 3)))
+    for stage in ("prepare", "dispatch", "collect"):
+        recs = tracer.records("engine:" + stage)
+        assert len(recs) == 3, stage
+        assert eng.stage_times[stage] == pytest.approx(
+            sum(r["t1"] - r["t0"] for r in recs)), stage

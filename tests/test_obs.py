@@ -142,6 +142,90 @@ def test_span_stop_idempotent_and_sync_callable_runs_before_clock():
     assert len(t.records("s")) == 1
 
 
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` trace written to
+    ``tmp_path``; returns the trace's ``dwpa:`` host events as
+    ``(name, start_ns, end_ns, stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("dwpa:"):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_spans_write_profiler_annotations(tmp_path):
+    """Every span is a ``dwpa:<name>`` event of the profiler trace,
+    nested as the spans nest, with its arguments; the start()/stop()
+    form and a child abandoned by an exception close theirs too."""
+    t = SpanTracer(MetricsRegistry())
+    feed = SpanTracer(MetricsRegistry(), args={"feed": "pass2"})
+
+    def body():
+        with t.span("work_unit", unit="0123abcd"):
+            with t.span("pass2"):
+                with feed.span("feed:wait"):
+                    pass
+        sp = t.start("prewarm")
+        sp.stop()
+        with pytest.raises(RuntimeError):
+            with t.span("outer"):
+                t.start("abandoned")  # never stopped
+                raise RuntimeError("skip the child's stop")
+
+    evs = {name: (s, e, stats) for name, s, e, stats in _profiled(tmp_path,
+                                                                 body)}
+    assert set(evs) == {"dwpa:work_unit", "dwpa:pass2", "dwpa:feed:wait",
+                        "dwpa:prewarm", "dwpa:outer", "dwpa:abandoned"}
+
+    def inside(child, parent):
+        return evs[parent][0] <= evs[child][0] <= evs[child][1] \
+            <= evs[parent][1]
+
+    assert inside("dwpa:pass2", "dwpa:work_unit")
+    assert inside("dwpa:feed:wait", "dwpa:pass2")
+    assert inside("dwpa:abandoned", "dwpa:outer")
+    assert evs["dwpa:work_unit"][2] == {"unit": "0123abcd"}
+    assert evs["dwpa:feed:wait"][2] == {"feed": "pass2"}
+    assert evs["dwpa:work_unit"][1] <= evs["dwpa:prewarm"][0]
+    assert [r["name"] for r in t.records()] == [
+        "pass2", "work_unit", "prewarm", "outer"]
+
+
+def test_span_annotation_closes_after_the_sync_fetch(tmp_path):
+    """The device-sync rule holds for the annotation as for the clock:
+    the ``sync=`` fetch runs inside the ``dwpa:`` event."""
+    import time as _time
+
+    t = SpanTracer(MetricsRegistry())
+
+    def body():
+        with t.span("collect", sync=lambda: _time.sleep(0.05)):
+            pass
+
+    (name, s, e, _), = _profiled(tmp_path, body)
+    assert name == "dwpa:collect"
+    assert e - s >= 0.05e9
+    rec, = t.records("collect")
+    assert 0.05 <= rec["t1"] - rec["t0"] <= (e - s) / 1e9
+
+
 # ---------------------------------------------------------------------------
 # logging
 # ---------------------------------------------------------------------------

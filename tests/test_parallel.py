@@ -174,3 +174,46 @@ def test_device_mask_words_matches_host_pack():
         dev = np.array(device_mask_words(mask, start, 16))
         ref = bo.pack_passwords_be(list(mask_words(mask, skip=start, limit=16)))
         np.testing.assert_array_equal(dev, ref, err_msg=f"{mask}@{start}")
+
+
+def test_crack_step_programs_are_named():
+    """Each jitted step lowers to a module under its own name (what a
+    profile's ``XLA Modules`` line shows), and the verify's ops carry
+    the ``verify/<kind>`` scope in their metadata."""
+    from dwpa_tpu.parallel import step as S
+
+    lines = [T.make_pmkid_line(PSK, ESSID, seed="nm1"),
+             T.make_eapol_line(PSK, ESSID, keyver=2, seed="nm2"),
+             T.make_eapol_line(PSK, ESSID, keyver=3, seed="nm3")]
+    nets = [m.prep_net(hl.parse(ln)) for ln in lines]
+    mesh = default_mesh(n=1)
+    s1, s2 = m.essid_salt_blocks(ESSID)
+    step = build_crack_step(mesh, nets, s1, s2)
+    assert callable(step.verify)
+    b = 8
+    pw = jax.ShapeDtypeStruct((b, 4), np.uint32)
+    pmk = jax.ShapeDtypeStruct((8, b), np.uint32)
+
+    def module(lowered):
+        text = lowered.as_text()
+        return text[text.index("module @") + 8:].split(" ", 1)[0]
+
+    assert module(S.pmk_step(mesh).lower(pw, s1, s2)) == "jit_pbkdf2_pmk"
+    kinds, found = set(), []
+    for (kind, static, _), idxs in S._partition(nets).items():
+        _, fields, _ = S._KINDS[kind]
+        group = [nets[i] for i in idxs]
+        consts = (np.ones(S._bucket(len(group)), bool),) + tuple(
+            S._pad_nets([getattr(g, f) for g in group]) for f in fields)
+        fn = S.verify_step(mesh, kind, static)
+        lowered = fn.lower(pmk, *consts)
+        assert module(lowered) == f"jit_verify_{kind}"
+        assert f"verify/{kind}" in lowered.as_text(debug_info=True)
+        found.append(jax.eval_shape(fn, pmk, *consts)[1])
+        kinds.add(kind)
+    assert kinds == {"pmkid", "eapol", "cmac"}
+    struct = tuple((len(idxs), f.shape[1]) for idxs, f in
+                   zip(S._partition(nets).values(), found))
+    v_max = max(v for _, v in struct)
+    asm = S._assemble_step(mesh, struct, v_max, None)
+    assert module(asm.lower(*found)) == "jit_verify_assemble"
