@@ -24,7 +24,9 @@ TPU-first design:
   NC-variant axis (one variant's hash state live at a time: a vmap over
   all variants needs several GB of HBM per net at B=131072), so
   compilations are shared across nets with the same (keyver,
-  n_variants, n_eapol_blocks) signature.
+  n_variants, n_eapol_blocks) signature.  On TPU the crack steps run
+  the keyver-1/2 check as a Pallas kernel instead (ops/eapol_pallas.py);
+  ``eapol_match`` is its XLA form, for every other platform.
 - All byte wrangling happens host-side in numpy; the device only ever sees
   fixed-shape uint32 arrays.
 """
@@ -281,7 +283,8 @@ def _pmkid_impl(pmk, msg_block, target):
 
 
 def eapol_match(pmk, prf_blocks, eapol_blocks, target, *, keyver):
-    """MIC match for keyver 1/2 over all NC variants.
+    """MIC match for keyver 1/2 over all NC variants (the XLA form; on
+    TPU ``parallel/step.py`` runs ``ops/eapol_pallas.py`` instead).
 
     ``pmk``: uint32[8, B]; ``prf_blocks``: uint32[V, 2, 16];
     ``eapol_blocks``: uint32[E, 16]; ``target``: uint32[4].

@@ -18,13 +18,16 @@ TPU) for every new work unit.  Here nothing net-specific is baked:
 
 - the PBKDF2 step takes the ESSID salt blocks as *data* — one compile
   per batch size serves every ESSID ever cracked;
-- the verify steps take the nets' constants as stacked arrays and
-  ``lax.map`` over the net axis (one net's working set at a time, so
-  a multi-BSSID group fits HBM at full batch), cached per shape signature
-  ``(kind, keyver, V variants, E eapol blocks)`` with the net count
-  padded up to a power-of-two bucket — a handful of compilations for a
-  server's whole lifetime, all shared across groups, engines and work
-  units.
+- the verify steps take the nets' constants as stacked arrays, cached
+  per shape signature ``(kind, keyver, V variants, E eapol blocks)``
+  with the net count padded up to a power-of-two bucket — a handful of
+  compilations for a server's whole lifetime, all shared across groups,
+  engines and work units.  On TPU the keyver-1/2 part runs as one Pallas
+  kernel (``ops/eapol_pallas.py``) whose grid walks the nets and whose
+  loop walks the NC variants, with the hash state in vector registers;
+  every other part, and every part off the TPU, ``lax.map``s its
+  per-net match over the net axis (one net's working set at a time, so
+  a multi-BSSID group fits HBM at full batch).
 
 This is the TPU mapping of the reference's work distribution (volunteer
 data parallelism + ESSID-amortized PBKDF2) described in SURVEY.md §5.7.
@@ -36,6 +39,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import m22000 as m
+from ..ops.eapol_pallas import eapol_mic_pallas
 from .mesh import DP_AXIS
 
 #: (mesh, kind, *static) -> jitted sharded step, shared process-wide.
@@ -60,6 +64,12 @@ def _shard(mesh, fn, in_specs, out_specs, name):
     )
 
 
+def _on_tpu(mesh) -> bool:
+    """Whether ``mesh`` is all TPU: the Pallas kernels run there, the
+    XLA formulations everywhere else."""
+    return all(d.platform == "tpu" for d in mesh.devices.flat)
+
+
 def pmk_step(mesh):
     """jitted ``(pw_words[B,16], salt1[16], salt2[16]) -> pmk uint32[8, B]``.
 
@@ -67,7 +77,7 @@ def pmk_step(mesh):
     """
     key = (mesh, "pmk")
     if key not in _STEP_CACHE:
-        use_pallas = all(d.platform == "tpu" for d in mesh.devices.flat)
+        use_pallas = _on_tpu(mesh)
 
         def local(pw_words, s1, s2):
             return m._pmk_impl(pw_words, s1, s2, use_pallas=use_pallas)
@@ -96,7 +106,7 @@ def fused_pmk_step(mesh):
     """
     key = (mesh, "pmk_fused")
     if key not in _STEP_CACHE:
-        use_pallas = all(d.platform == "tpu" for d in mesh.devices.flat)
+        use_pallas = _on_tpu(mesh)
 
         def local(pw_words, unit_id, t1, t2):
             return m._pmk_impl(pw_words, t1[unit_id], t2[unit_id],
@@ -164,6 +174,25 @@ def _scoped(kind, match):
 _KINDS = {kind: (statics, fields, _scoped(kind, match))
           for kind, (statics, fields, match) in _KINDS.items()}
 
+# Kinds whose whole part (every net, every variant) runs as one Pallas
+# kernel on TPU: (pmk[8,b], static tuple, *stacked consts) -> bool[N, V, b].
+_PART_KERNELS = {
+    "eapol": _scoped("eapol", lambda pmk, st, prf, eap, tgt: eapol_mic_pallas(
+        pmk, prf, eap, tgt, keyver=st[0])),
+}
+
+
+def _part_match(kind, static, use_pallas):
+    """``(pmk[8, b], *stacked consts) -> bool[N, V, b]`` for one part:
+    the kind's Pallas kernel where the step runs on TPU and the kind has
+    one, else its per-net match ``lax.map``'d over the net axis."""
+    kernel = _PART_KERNELS.get(kind) if use_pallas else None
+    if kernel is not None:
+        return lambda pmk, *consts: kernel(pmk, static, *consts)
+    match = _KINDS[kind][2]
+    return lambda pmk, *consts: jax.lax.map(
+        lambda cs: match(pmk, static, *cs), consts)
+
 
 def _kind_of(net) -> str:
     if net.keyver == 100:
@@ -179,11 +208,11 @@ def verify_step(mesh, kind, static):
     """
     key = (mesh, kind, static)
     if key not in _STEP_CACHE:
-        _, fields, match = _KINDS[kind]
+        fields = _KINDS[kind][1]
+        match = _part_match(kind, static, _on_tpu(mesh))
 
         def local(pmk, mask, *consts):
-            fnd = jax.lax.map(lambda cs: match(pmk, static, *cs), consts)
-            return _gate(fnd, mask)
+            return _gate(match(pmk, *consts), mask)
 
         _STEP_CACHE[key] = _shard(
             mesh,
@@ -298,7 +327,8 @@ def build_rules_step(mesh, nets, salt1, salt2):
     earlier remote setup and is unmeasured on a local chip.
 
     Each net, and each NC variant inside a net, is verified in turn
-    (``lax.map``), not all at once: materialising ``[N, V, B]``
+    (``lax.map``, or on TPU the keyver-1/2 kernel's own net grid and
+    variant loop), not all at once: materialising ``[N, V, B]``
     intermediates of the PRF/MIC hashes at B=131072 needs more than a
     v5e's 16 GB of HBM.
 
@@ -320,12 +350,12 @@ def build_rules_step(mesh, nets, salt1, salt2):
     repl = NamedSharding(mesh, P())
     s1 = jax.device_put(np.asarray(salt1), repl)
     s2 = jax.device_put(np.asarray(salt2), repl)
-    use_pallas = all(d.platform == "tpu" for d in mesh.devices.flat)
+    use_pallas = _on_tpu(mesh)
 
     parts = []
     for sig, idxs in _partition(nets).items():
         kind, static = sig[0], sig[1]
-        _, fields, match = _KINDS[kind]
+        fields = _KINDS[kind][1]
         group = [nets[i] for i in idxs]
         mask = np.zeros(_bucket(len(group)), dtype=bool)
         mask[: len(group)] = True
@@ -333,7 +363,8 @@ def build_rules_step(mesh, nets, salt1, salt2):
             _pad_nets([getattr(g, f) for g in group]) for f in fields
         )
         consts = tuple(jax.device_put(c, repl) for c in consts)
-        parts.append((kind, static, match, consts))
+        parts.append((kind, static, _part_match(kind, static, use_pallas),
+                      consts))
 
     key = (mesh, "rules_step", use_pallas,
            tuple((p[0], p[1]) for p in parts),
@@ -359,9 +390,7 @@ def build_rules_step(mesh, nets, salt1, salt2):
                 any_l = None
                 for (kind, static, match, _nc), consts in zip(meta, pcs):
                     mask = consts[0]
-                    fnd = jax.lax.map(
-                        lambda cs: match(pmk, static, *cs), consts[1:])
-                    fnd = fnd & mask[:, None, None]
+                    fnd = match(pmk, *consts[1:]) & mask[:, None, None]
                     hits_l = hits_l + jnp.sum(fnd, dtype=jnp.int32)
                     a = fnd.any(axis=(0, 1))  # [b]
                     any_l = a if any_l is None else (any_l | a)

@@ -92,6 +92,27 @@ def test_fused_pmk_and_mix_steps_compile(mesh):
     _fits(mix)
 
 
+def test_eapol_verify_step_runs_its_kernel(mesh):
+    """``jit_verify_eapol`` for two keyver-2 nets at NC 8 (21 variants,
+    3 EAPOL blocks), the part ``eapol-group-dict`` sends: on the chip the
+    whole part is the keyver-1/2 kernel, under its own op name."""
+    n, v, e = 2, 21, 3
+    fn = st.verify_step(mesh, "eapol", (2,))
+    repl = P()
+    lowered = fn.lower(
+        _sds(mesh, (8, B), jnp.uint32, P(None, "dp")),
+        _sds(mesh, (n,), jnp.bool_, repl),
+        _sds(mesh, (n, v, 2, 16), jnp.uint32, repl),
+        _sds(mesh, (n, e, 16), jnp.uint32, repl),
+        _sds(mesh, (n, 4), jnp.uint32, repl))
+    assert "module @jit_verify_eapol" in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%eapol_mic_pallas" in text
+    _fits(compiled)
+
+
 def _multi_bssid_group(monkeypatch):
     """chip_smoke's largest group — 2 EAPOL keyver 2, 2 keyver 3 (CMAC)
     and a PMKID under one ESSID, NC 8 (21 variants each).  The step
