@@ -36,6 +36,7 @@ from ..gen import DictStream, psk_candidates
 from ..gen.mask import mask_blocks
 from ..models import hashline as hl
 from ..models.m22000 import M22000Engine
+from ..parallel.streams import RETRIES_HELP
 from ..obs import (SpanTracer, default_registry, get_logger, is_emitter,
                    merged_slice_snapshot, setup_logging)
 from ..rules import apply_rules, parse_rules
@@ -250,8 +251,7 @@ class TpuCrackClient:
         self._m_founds = reg.counter(
             "dwpa_client_founds_total", "cracked PSKs recovered")
         self._m_engine_retries = reg.counter(
-            "dwpa_client_engine_retries_total",
-            "work units retried in-process after an engine error")
+            "dwpa_client_engine_retries_total", RETRIES_HELP)
         # Fused-executor families are registered up front (idempotent by
         # name — fused_executor() binds the same series) so a metrics
         # scrape shows them at zero before the first fused wave runs.
@@ -275,6 +275,11 @@ class TpuCrackClient:
                   "prepare/dispatch/collect (1 - shared-queue wait)")
         reg.gauge("dwpa_stream_queue_depth",
                   "Shared work-queue depth at this stream's last pull")
+        reg.counter("dwpa_stream_wait_seconds_total",
+                    "Seconds a device stream waited on the shared queue "
+                    "with no block of its own")
+        reg.counter("dwpa_stream_requeues_total",
+                    "Blocks requeued after this device's stream failed")
         if config.additional_dict and jax.process_count() > 1:
             # A per-host local file cannot feed a multi-host slice: the
             # pass-1 streams must be byte-identical on every host or the
@@ -450,7 +455,9 @@ class TpuCrackClient:
         unusual (V variants, EAPOL blocks) bucket; the dominant PBKDF2
         trace is shared regardless.  With the persistent cache (see
         __init__) the compile happens once per installation; afterwards
-        this is ~0.2 s of device work.
+        this is ~0.2 s of device work.  Under device streams every local
+        device's single-device engine is warmed this way, since each
+        stream compiles its programs for its own chip.
 
         The fused device-rules step of pass 2 is not warmed: its program
         is keyed on the unit's own net partition, which a synthetic warm
@@ -461,23 +468,50 @@ class TpuCrackClient:
         # perf_counter, not time.time(): an NTP step mid-prewarm must not
         # corrupt the logged duration (same rule as the pacing clock)
         sp = self.tracer.start("prewarm")
-        eng = M22000Engine(
-            [
-                synth.make_pmkid_line(CHALLENGE_PSK, b"dlink", seed="challenge-p"),
-                synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=1,
-                                      seed="warm-k1"),
-                synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=2,
-                                      seed="challenge-e"),
-                synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=3,
-                                      seed="warm-k3"),
-            ],
-            nc=self.cfg.nc, batch_size=self.cfg.batch_size,
-            tracer=self.tracer,
-        )
+        lines = [
+            synth.make_pmkid_line(CHALLENGE_PSK, b"dlink", seed="challenge-p"),
+            synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=1,
+                                  seed="warm-k1"),
+            synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=2,
+                                  seed="challenge-e"),
+            synth.make_eapol_line(CHALLENGE_PSK, b"dlink", keyver=3,
+                                  seed="warm-k3"),
+        ]
+        eng = M22000Engine(lines, nc=self.cfg.nc,
+                           batch_size=self.cfg.batch_size,
+                           tracer=self.tracer)
+        if self._use_streams():
+            # Every stream's own single-device engine, each in a thread
+            # of its own so that the chips compile side by side: a block
+            # per bucket through the shared queue would warm at most
+            # three streams, each at one width.
+            from concurrent.futures import ThreadPoolExecutor
+
+            from ..parallel import default_mesh
+
+            devices = list(eng.mesh.devices.flat)
+
+            def warm(device):
+                self._warm_engine(M22000Engine(
+                    lines, nc=self.cfg.nc, batch_size=eng.batch_size,
+                    mesh=default_mesh(devices=[device]),
+                    tracer=self.tracer), producers=1)
+
+            with ThreadPoolExecutor(len(devices)) as ex:
+                for f in [ex.submit(warm, d) for d in devices]:
+                    f.result()
+        else:
+            self._warm_engine(eng, producers=self._feed_workers())
+        # crack_blocks syncs internally (hits gate), so the span's clock
+        # stops after real device completion
+        sp.stop()
+        self.log(f"prewarm: work-size steps ready in {sp.seconds:.1f}s")
+
+    def _warm_engine(self, eng, producers: int):
+        """One block per width bucket through the candidate feed and
+        ``eng.crack_blocks``: the feed -> stage -> dispatch path real
+        units take, warmed with it."""
         n = eng.batch_size
-        # The three width buckets stream through the candidate feed —
-        # one block per bucket — so prewarm also exercises (and warms)
-        # the exact feed -> stage -> dispatch path real units take.
         warm_words = itertools.chain(
             (b"warm-%08d" % i for i in range(n)),
             (b"warm-long-padding-%08d" % i for i in range(n)),
@@ -485,20 +519,13 @@ class TpuCrackClient:
              for i in range(n)),
         )
         feed = CandidateFeed(warm_words, batch_size=n,
-                             depth=self.cfg.feed_depth,
-                             producers=self._feed_workers(),
+                             depth=self.cfg.feed_depth, producers=producers,
                              prepack=eng.host_packer(),
                              registry=self.registry, name="prewarm")
         try:
-            # Streams mode warms the per-device single-mesh engines (the
-            # shapes real units hit); lockstep warms the shard_map path.
-            self._crack_blocks(eng, feed)
+            eng.crack_blocks(feed)
         finally:
             feed.close()
-        # crack_blocks syncs internally (hits gate), so the span's clock
-        # stops after real device completion
-        sp.stop()
-        self.log(f"prewarm: work-size steps ready in {sp.seconds:.1f}s")
 
     # -- work-unit plumbing ------------------------------------------------
 

@@ -1069,6 +1069,11 @@ class M22000Engine:
     #: matrices are MBs.
     SMALL_FETCH_BYTES = 600_000
 
+    #: A find batch's PMK matrix up to this size is fetched whole with
+    #: its match matrix; a larger one gives up only its hit columns
+    #: (``parallel.step.gather_step``, ``jit_find_gather``).
+    SMALL_PMK_BYTES = 1 << 21
+
     def _replicated(self, x):
         """Reshard a batch-sharded step output to fully replicated.
 
@@ -1312,7 +1317,7 @@ class M22000Engine:
                                        lambda b: pmk_host[:, b], pws,
                                        psk_by_col, live)
                 continue
-            if pmk_dev.nbytes <= (1 << 21):
+            if pmk_dev.nbytes <= self.SMALL_PMK_BYTES:
                 # Small batch: one merged fetch of both arrays (one D2H
                 # call instead of two; this path is in every small work
                 # unit's constant overhead).
@@ -1328,16 +1333,9 @@ class M22000Engine:
                 found[:, :, nvalid:] = False
                 cols = np.flatnonzero(found.any(axis=(0, 1)))
                 if len(cols) <= self.MAX_FINDS_PER_BATCH:
-                    gather = getattr(self, "_pmk_gather_jit", None)
-                    if gather is None:
-                        # named for the profile (``jit_find_gather``);
-                        # still one jit per engine, so each engine's
-                        # first find compiles it
-                        def find_gather(p, c):
-                            return p[..., c]
+                    from ..parallel.step import gather_step
 
-                        gather = self._pmk_gather_jit = jax.jit(
-                            find_gather)
+                    gather = gather_step(self.mesh)
                     pad = np.zeros(self.MAX_FINDS_PER_BATCH, np.int32)
                     pad[: len(cols)] = cols
                     pmk_cols = np.asarray(gather(pmk_dev, pad))
@@ -1360,6 +1358,18 @@ class M22000Engine:
                 pmk_host = jax.device_get(pmk_dev)
                 self.pmk_store.put(essid, miss_words, pmk_host[:, :nmiss])
         return founds
+
+    def warm_groups(self):
+        """Compile each live group's verify and the find gather on this
+        engine's mesh, by one run on an all-zero PMK matrix, where this
+        process has not run them there yet.  A device stream calls it
+        before its first block, so a group shape that one stream meets
+        first is ready on every stream (``parallel/streams.py``)."""
+        from ..parallel.step import warm_verify
+
+        for essid in list(self.groups):
+            warm_verify(self._step_for(essid), self.batch_size,
+                        self.MAX_FINDS_PER_BATCH)
 
     def crack_batch(self, passwords) -> list:
         """One fixed-size batch of candidate byte-strings -> list[Found]."""
@@ -2213,6 +2223,11 @@ class _RulesStreamEngine:
 
     def remove(self, found):
         self.inner.remove(found)
+
+    def warm_groups(self):
+        """Nothing: a rules step is keyed on the unit's own net
+        partition and compiles at its first chunk, as the client's
+        ``prewarm`` says."""
 
     def _prepare_block(self, block):
         eng = self.inner

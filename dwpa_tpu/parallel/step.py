@@ -224,6 +224,37 @@ def verify_step(mesh, kind, static):
     return _STEP_CACHE[key]
 
 
+def gather_step(mesh):
+    """jitted ``(pmk[8, B], cols[F]) -> pmk[8, F]``: the PMKs of a find
+    batch's hit columns (``M22000Engine._collect``), one jit per mesh
+    for every engine of the process."""
+    key = (mesh, "find_gather")
+    if key not in _STEP_CACHE:
+
+        def find_gather(p, c):
+            return p[..., c]
+
+        _STEP_CACHE[key] = jax.jit(find_gather)
+    return _STEP_CACHE[key]
+
+
+def warm_verify(step, batch: int, finds: int):
+    """Run ``step``'s verify and ``gather_step`` once on an all-zero PMK
+    matrix of ``batch`` columns (``finds`` gathered), unless this
+    process already ran the same programs on the step's mesh: compiles
+    what a find batch of this group shape runs before its first block."""
+    key = ("warm", step.programs, batch, finds)
+    if key in _STEP_CACHE:
+        return
+    mesh = step.programs[0]
+    pmk = jax.device_put(np.zeros((8, batch), np.uint32),
+                         NamedSharding(mesh, P(None, DP_AXIS)))
+    hits, found, _ = step.verify(pmk)
+    cols = gather_step(mesh)(pmk, np.zeros(finds, np.int32))
+    jax.block_until_ready((hits, found, cols))
+    _STEP_CACHE[key] = True
+
+
 def _bucket(n: int) -> int:
     """Pad net counts to powers of two so jit's shape cache hits across
     groups of nearby sizes."""
@@ -492,4 +523,7 @@ def build_crack_step(mesh, nets, salt1, salt2):
     # mix_step, or fully cached) — same jit caches either way.
     step.compute_pmk = compute_pmk
     step.verify = verify
+    # what ``verify`` compiles, on which mesh (``warm_verify``'s key)
+    step.programs = (mesh, tuple(
+        (fn, tuple(c.shape for c in consts)) for fn, consts in parts), asm)
     return step

@@ -70,6 +70,11 @@ def default_feed_workers() -> int:
     return max(1, jax.local_device_count())
 
 
+#: help of the client's retry counter, which a stream requeue also counts
+RETRIES_HELP = ("work units retried in-process after an engine error, "
+                "and blocks requeued after a device stream failed")
+
+
 def device_label(device) -> str:
     """Stable ``platform:id`` metric label for one device."""
     return f"{getattr(device, 'platform', 'dev')}:{getattr(device, 'id', 0)}"
@@ -184,9 +189,11 @@ class DeviceStream:
     Wraps a single-device engine (a 1-device mesh — ``shard_candidates``
     over it is an explicit ``jax.device_put`` onto exactly this device)
     plus the stream's telemetry: ``dwpa_stream_blocks_total`` /
-    ``dwpa_stream_busy_fraction`` / ``dwpa_stream_queue_depth``, all
-    labeled ``device=platform:id``, and ``stream:dispatch`` /
-    ``stream:collect`` spans.
+    ``dwpa_stream_busy_fraction`` / ``dwpa_stream_queue_depth`` /
+    ``dwpa_stream_wait_seconds_total`` / ``dwpa_stream_requeues_total``,
+    all labeled ``device=platform:id``, and ``stream:wait`` (a blocking
+    pull from the shared queue) / ``stream:dispatch`` /
+    ``stream:collect`` spans, each annotated ``device=platform:id``.
     """
 
     def __init__(self, index, device, engine, registry=None, tracer=None):
@@ -213,13 +220,24 @@ class DeviceStream:
                 "dwpa_stream_queue_depth",
                 "Shared work-queue depth at this stream's last pull"
             ).labels(**lbl)
+            self._m_wait = registry.counter(
+                "dwpa_stream_wait_seconds_total",
+                "Seconds a device stream waited on the shared queue "
+                "with no block of its own").labels(**lbl)
+            self._m_requeues = registry.counter(
+                "dwpa_stream_requeues_total",
+                "Blocks requeued after this device's stream failed"
+            ).labels(**lbl)
+            self._m_retries = registry.counter(
+                "dwpa_client_engine_retries_total", RETRIES_HELP)
         else:
             self._m_blocks = self._m_busy = self._m_qdepth = None
+            self._m_wait = self._m_requeues = self._m_retries = None
 
     def _span(self, name):
         if self.tracer is None:
             return contextlib.nullcontext()
-        return self.tracer.span(name)
+        return self.tracer.span(name, device=self.label)
 
     def run_blocks(self, next_item, on_result=None) -> list:
         """Crack framed blocks pulled from ``next_item`` on this
@@ -247,6 +265,9 @@ class DeviceStream:
         device-sync rule.  Returns the stream's Found list.
         """
         eng = self.engine
+        # every live group's programs on this device before its first
+        # block, whichever stream pulls which block
+        eng.warm_groups()
         pending = collections.deque()  # (block, dispatched | None)
         founds = []
         t_run = time.perf_counter()
@@ -357,8 +378,15 @@ class StreamExecutor:
         while st.prune:
             st.engine.remove(st.prune.popleft())
         t0 = time.perf_counter()
-        item = self._q.get(st.index, block=block_ok)
-        st.wait_s += time.perf_counter() - t0
+        if block_ok:
+            with st._span("stream:wait"):
+                item = self._q.get(st.index, block=True)
+        else:
+            item = self._q.get(st.index, block=False)
+        waited = time.perf_counter() - t0
+        st.wait_s += waited
+        if block_ok and st._m_wait is not None:
+            st._m_wait.inc(waited)
         if st._m_qdepth is not None:
             st._m_qdepth.set(self._q.depth)
         if item is None or item is _STALL:
@@ -384,7 +412,10 @@ class StreamExecutor:
         """Excluded-style retry (sched/executor.py's requeue contract):
         the dead stream's unfinished blocks go back to the queue with
         this stream excluded; a block out of eligible streams or past
-        ``max_attempts`` aborts the run with a ``StreamError``."""
+        ``max_attempts`` aborts the run with a ``StreamError``.  Each
+        requeue counts in ``dwpa_stream_requeues_total`` and in
+        ``dwpa_client_engine_retries_total``: a fault another chip hid
+        is still a retry."""
         with self._cond:
             self._alive.discard(st.index)
             alive = set(self._alive)
@@ -397,6 +428,9 @@ class StreamExecutor:
                   and bool(alive - item.excluded))
             if fatal is None and ok:
                 self._q.put(item, requeue=True)
+                if st._m_requeues is not None:
+                    st._m_requeues.inc()
+                    st._m_retries.inc()
             else:
                 # Resolve even the unretryable blocks so the queue's
                 # inflight count drains to zero and surviving workers

@@ -22,6 +22,8 @@ from dwpa_tpu.client.main import ClientConfig, TpuCrackClient
 from dwpa_tpu.client.protocol import NoNets, ServerAPI, VersionRejected
 from dwpa_tpu.models import hashline as hl
 from dwpa_tpu.obs import MetricsRegistry
+from dwpa_tpu.obs import spans as obs_spans
+from dwpa_tpu.parallel.streams import device_label
 from dwpa_tpu.server import Database, ServerCore, make_wsgi_app
 
 PSK = b"loopback-psk1"
@@ -190,10 +192,18 @@ def test_resume_rejected_on_batch_size_change(server, tmp_path):
     assert same._read_resume() == work
 
 
-def test_metrics_after_one_work_unit(server, tmp_path):
+def test_metrics_after_one_work_unit(server, tmp_path, monkeypatch):
     """Telemetry contract for one loopback unit (the ISSUE-2 acceptance
     check): transport counters for get_work/put_work, a nonzero PMK/s
     gauge, the autotune/dictcount instruments, and well-nested spans."""
+    annotated = []   # (span name, profiler-annotation arguments)
+    real_annotate = obs_spans._annotate
+
+    def annotate(name, args):
+        annotated.append((name, dict(args)))
+        return real_annotate(name, args)
+
+    monkeypatch.setattr(obs_spans, "_annotate", annotate)
     _ingest(server, [tfx.make_pmkid_line(PSK, ESSID, seed="mt1")])
     _add_dict(server, [b"filler-000001", PSK, b"filler-000002"])
     reg = MetricsRegistry()
@@ -286,8 +296,57 @@ def test_metrics_after_one_work_unit(server, tmp_path):
             assert 0.0 <= busy <= 1.0, (labels, busy)
         for labels, depth in reg.series("dwpa_stream_queue_depth").items():
             assert depth >= 0, (labels, depth)
-        assert {"stream:dispatch", "stream:collect"} <= \
+        assert {"stream:wait", "stream:dispatch", "stream:collect"} <= \
             {r["name"] for r in recs}
+        # every stream waited at least on its first pull; the seconds
+        # are its stream:wait spans', per device
+        waits = reg.series("dwpa_stream_wait_seconds_total")
+        assert len(waits) == jax.local_device_count()
+        assert all(v >= 0 for v in waits.values())
+        assert not any(reg.series("dwpa_stream_requeues_total").values())
+        assert not reg.value("dwpa_client_engine_retries_total")
+        # each stream span names its chip in the profiler trace
+        labels = {device_label(d) for d in jax.local_devices()}
+        stream_args = [a for n, a in annotated if n.startswith("stream:")]
+        assert {a["device"] for a in stream_args} <= labels
+        assert all("device" in a for a in stream_args)
+        assert any(n == "stream:wait" for n, _ in annotated)
+
+
+def test_streams_compile_nothing_after_warm_up(server, tmp_path,
+                                              monkeypatch):
+    """Device streams: after ``prewarm`` and one warm-up unit per group
+    shape, each cracked in pass 1 (one block, so one stream runs it),
+    a further unit of each shape compiles nothing on whichever streams
+    pull its blocks."""
+    from dwpa_tpu import parallel
+    from dwpa_tpu.analysis import watch_compiles
+
+    # two streams bound the compile bill of a CPU run
+    mesh_of = parallel.default_mesh
+    monkeypatch.setattr(parallel, "default_mesh", lambda devices=None:
+                        mesh_of(devices or jax.devices()[:2]))
+    words = [b"streamwarm-%06d" % i for i in range(1280)]   # 10 blocks
+    _add_dict(server, words)
+    # at 128 a pass-1 block holds both warm-up nets' candidates
+    client = _client(server, tmp_path, device_streams="on", batch_size=128)
+    assert client._use_streams()
+    client.prewarm()
+
+    def unit(essid, psks, seed):
+        _ingest(server, [tfx.make_pmkid_line(p, essid, seed=f"{seed}-{i}")
+                         for i, p in enumerate(psks)])
+        res = client.process_work(client.api.get_work(client.dictcount))
+        assert res.accepted and res.founds
+
+    # warm-up units: the ESSID is the PSK, a pass-1 candidate
+    unit(b"WarmOneNet", [b"WarmOneNet"], "sw1")
+    unit(b"WarmTwoNet", [b"WarmTwoNet"] * 2, "sw2")
+    with watch_compiles() as comp:
+        # a find deep in the dictionary; the other net runs it through
+        unit(b"WindowTwo", [words[1000], b"not-in-the-dict"], "sw3")
+        unit(b"WindowOne", [words[1200]], "sw4")
+    assert comp.count == 0, comp.names
 
 
 def test_pmkstore_metrics_and_warm_unit(server, tmp_path):

@@ -164,3 +164,20 @@ def test_randomized_differential_vs_oracle():
         assert (f.psk, f.pmk) == (psk, ref[3])
         assert f.nc == (ref[1] or 0)
         assert (f.endian or "") == (ref[2] or "")
+
+
+def test_find_gather_compiles_once_per_mesh(monkeypatch):
+    """Engines on one mesh and batch shape share the find gather: a
+    second engine's find compiles no ``jit_find_gather`` of its own, and
+    the gathered PMK is the oracle's."""
+    from dwpa_tpu.analysis import watch_compiles
+
+    # every find batch takes the gather path, as a chip-sized batch does
+    monkeypatch.setattr(M22000Engine, "SMALL_PMK_BYTES", 0)
+    psk = b"gatherpass1"
+    line = tfx.make_pmkid_line(psk, b"GatherNet")
+    with watch_compiles() as comp:
+        crack_one(line, psk)
+        crack_one(line, psk)
+    # at most one: another test of this process may have compiled it
+    assert comp.names.count("jit(find_gather)") <= 1

@@ -192,6 +192,9 @@ class _FakeEngine:
         self.fail_offsets = set(fail_offsets)
         self.seen = []
 
+    def warm_groups(self):
+        pass
+
     def _prepare_block(self, block):
         return block
 
@@ -220,7 +223,9 @@ def _fake_devices(k):
 def test_stream_crash_requeues_to_survivor():
     """Stream 0 dies mid-run: its unfinished blocks go back to the
     queue with stream 0 excluded, the survivor completes them, demux
-    order and counts are unchanged, and no stream thread leaks."""
+    order and counts are unchanged, and no stream thread leaks.  Each
+    requeue is counted for stream 0's device and as an engine retry,
+    so a fault the survivor hid still shows."""
     lines = [object(), object()]
     engines = {}
 
@@ -229,7 +234,8 @@ def test_stream_crash_requeues_to_survivor():
         engines[device.id] = _FakeEngine(lines, fail_offsets=fail)
         return engines[device.id]
 
-    ex = StreamExecutor(factory, _fake_devices(2))
+    reg = MetricsRegistry()
+    ex = StreamExecutor(factory, _fake_devices(2), registry=reg)
     blocks = _fake_blocks(6)
     log = []
     founds = ex.run(iter(blocks), on_batch=lambda c, f: log.append(c))
@@ -240,6 +246,10 @@ def test_stream_crash_requeues_to_survivor():
     assert ex.block_streams[2] == 1
     assert 64 in engines[1].seen and 64 not in engines[0].seen
     assert _stream_threads() == []
+    requeued = reg.value("dwpa_stream_requeues_total", device="fake:0")
+    assert requeued >= 1                        # at least the poisoned one
+    assert not reg.value("dwpa_stream_requeues_total", device="fake:1")
+    assert reg.value("dwpa_client_engine_retries_total") == requeued
 
 
 def test_stream_crash_out_of_streams_is_fatal():
