@@ -24,17 +24,23 @@ Unlike the old slicer, a host no longer materializes the full
 fall inside this host's slice are buffered.  Because
 ``blk(n) = min(batch_size, ceil(n / nproc))`` is nondecreasing in the
 final block length ``n``, the slice window only ever moves right as the
-block grows — so a word at block-index ``i`` is kept iff
+block grows — so a word at block-index ``i`` can be kept only if
 ``pid * blk(i + 1) <= i < (pid + 1) * batch_size``, and the buffer's
 left edge is pruned to ``pid * blk(c)`` as the consumed count ``c``
 grows.  Peak buffering is ``(pid + 1) * batch_size - pid * blk(c)``
 (<= ``(pid + 1)(nproc - pid)/nproc * batch_size``, exactly
 ``batch_size`` for full blocks and for host 0) versus the former
 ``batch_size * nproc`` on every host.
+
+Words are taken from the source in slices (``itertools.islice``), never
+one per interpreter loop iteration: host 0's window never moves, so it
+takes its ``batch_size`` words in one slice and counts the rest of the
+global block without keeping it; any other host takes ``_CHUNK`` words
+at a time and applies the window arithmetic per chunk, so its buffer is
+the bound above plus the one chunk in hand.
 """
 
 import itertools
-from collections import deque
 from dataclasses import dataclass
 
 import numpy as np
@@ -74,6 +80,10 @@ def _blk(n: int, batch_size: int, nproc: int) -> int:
     return min(batch_size, -(-n // nproc))
 
 
+#: words a host other than 0 takes from the source per slice
+_CHUNK = 8192
+
+
 def frame_blocks(words, batch_size: int, nproc: int = 1, pid: int = 0,
                  pad_word: bytes = b"", base_offset: int = 0,
                  watermark: list = None):
@@ -88,29 +98,32 @@ def frame_blocks(words, batch_size: int, nproc: int = 1, pid: int = 0,
     it = iter(words)
     gsize = batch_size * nproc
     hi = (pid + 1) * batch_size
+    step = hi if pid == 0 else _CHUNK
     offset = base_offset
     while True:
-        buf = deque()  # (block-index, word), indices strictly increasing
-        peak = c = 0
-        for w in it:
-            i = c
-            c += 1
-            if i < hi and pid * _blk(i + 1, batch_size, nproc) <= i:
-                buf.append((i, w))
-            # prune the left edge: the final window start can only grow
-            start = pid * _blk(c, batch_size, nproc)
-            while buf and buf[0][0] < start:
-                buf.popleft()
+        buf = []      # block indices [lo, min(c, hi)) of this window
+        lo = c = peak = 0
+        while c < hi:
+            want = min(step, hi - c)
+            chunk = list(itertools.islice(it, want))
+            a, c = c, c + len(chunk)
+            # the final window starts at or after pid * blk(c)
+            start = min(pid * _blk(c, batch_size, nproc), c)
+            del buf[:start - lo]
+            buf += chunk[start - a:] if start > a else chunk
+            lo = start
             peak = max(peak, len(buf))
-            if c == gsize:
-                break
-        if watermark is not None and c:
-            watermark.append(peak)
+            if len(chunk) < want:
+                break     # the source ended inside this block
+        if c == hi:
+            c += skip_stream(it, gsize - hi)   # counted, not kept
         if c == 0:
             return
+        if watermark is not None:
+            watermark.append(peak)
         blk = _blk(c, batch_size, nproc)
-        start = pid * blk
-        mine = [w for i, w in buf if i < start + blk]
+        i = pid * blk - lo
+        mine = buf[i:i + blk]
         nreal = len(mine)
         mine += [pad_word] * (blk - nreal)
         yield Block(offset=offset, count=c, words=mine, padded=(nreal == 0))

@@ -38,13 +38,15 @@ Design contracts:
 
 Metric names (README "Candidate feed"): ``dwpa_feed_queue_depth``
 (gauge), ``dwpa_feed_producer_stall_seconds`` /
-``dwpa_feed_consumer_starve_seconds`` (histograms),
-``dwpa_feed_blocks_total`` / ``dwpa_feed_candidates_total`` /
-``dwpa_feed_bytes_total`` (counters) — all labeled ``feed=<name>`` —
-plus ``feed:skip`` / ``feed:produce`` / ``feed:wait`` spans in
-``dwpa_span_seconds``, each annotated ``feed=<name>`` in the profiler
-trace.  ``feed:wait`` is the consumer's wait, and the starve histogram
-observes its seconds.
+``dwpa_feed_consumer_starve_seconds`` / ``dwpa_feed_frame_seconds``
+(histograms), ``dwpa_feed_blocks_total`` / ``dwpa_feed_candidates_total``
+/ ``dwpa_feed_bytes_total`` (counters) — all labeled ``feed=<name>`` —
+plus ``feed:skip`` / ``feed:frame`` / ``feed:produce`` / ``feed:wait``
+spans in ``dwpa_span_seconds``, each annotated ``feed=<name>`` in the
+profiler trace.  ``feed:wait`` is the consumer's wait, and the starve
+histogram observes its seconds; ``feed:frame`` is one framing call
+under the source lock (the source read included), and the frame
+histogram observes its seconds.
 """
 
 import threading
@@ -146,6 +148,11 @@ class CandidateFeed:
             "dwpa_feed_producer_stall_seconds",
             "per-block producer wait on a full feed queue (backpressure)"
         ).labels(**lbl)
+        self._m_frame = reg.histogram(
+            "dwpa_feed_frame_seconds",
+            "per-call framing time under the source lock (source read "
+            "included), per block and at the stream's end"
+        ).labels(**lbl)
         self._m_starve = reg.histogram(
             "dwpa_feed_consumer_starve_seconds",
             "consumer wait on an empty feed queue (host too slow), per "
@@ -192,7 +199,11 @@ class CandidateFeed:
     def _frame_next(self):
         """-> (seq, Block | None) under ``_src_lock``; None = exhausted."""
         self._do_skip()
-        blk = next(self._frames, None)
+        frame = self.tracer.start("feed:frame")
+        try:
+            blk = next(self._frames, None)
+        finally:
+            self._m_frame.observe(frame.stop())
         seq = self._next_frame
         self._next_frame += 1
         if blk is not None:

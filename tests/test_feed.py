@@ -13,6 +13,7 @@ values.
 import itertools
 import threading
 import time
+from collections import deque
 
 import pytest
 
@@ -20,6 +21,7 @@ import jax
 
 from dwpa_tpu import testing as tfx
 from dwpa_tpu.feed import Block, CandidateFeed, DeviceStager, FeedError
+from dwpa_tpu.feed import framing
 from dwpa_tpu.feed.framing import frame_blocks, skip_stream
 from dwpa_tpu.models.m22000 import M22000Engine
 from dwpa_tpu.obs import MetricsRegistry
@@ -38,6 +40,43 @@ def _legacy_shard_word_blocks(words, nproc, pid, batch_size, pad_word=b""):
         mine = block[pid * blk:(pid + 1) * blk]
         mine += [pad_word] * (blk - len(mine))
         yield mine, len(block)
+
+
+def _per_word_frame_blocks(words, batch_size, nproc=1, pid=0, pad_word=b"",
+                           base_offset=0):
+    """The framer before slice framing, verbatim but for the watermark:
+    one interpreter loop iteration per source word, pruning the window's
+    left edge as the block grows — the reference for the whole ``Block``
+    sequence the slice framer must reproduce."""
+    blk_of = framing._blk
+    it = iter(words)
+    gsize = batch_size * nproc
+    hi = (pid + 1) * batch_size
+    offset = base_offset
+    while True:
+        buf = deque()
+        c = 0
+        for w in it:
+            i = c
+            c += 1
+            if i < hi and pid * blk_of(i + 1, batch_size, nproc) <= i:
+                buf.append((i, w))
+            start = pid * blk_of(c, batch_size, nproc)
+            while buf and buf[0][0] < start:
+                buf.popleft()
+            if c == gsize:
+                break
+        if c == 0:
+            return
+        blk = blk_of(c, batch_size, nproc)
+        start = pid * blk
+        mine = [w for i, w in buf if i < start + blk]
+        nreal = len(mine)
+        mine += [pad_word] * (blk - nreal)
+        yield Block(offset=offset, count=c, words=mine, padded=(nreal == 0))
+        offset += c
+        if c < gsize:
+            return
 
 
 def _feed_threads():
@@ -65,6 +104,36 @@ def test_framing_identical_to_legacy_slicer():
                     ref = list(_legacy_shard_word_blocks(
                         iter(words), nproc, pid, bs))
                     assert got == ref, (n, nproc, pid, bs)
+
+
+@pytest.mark.parametrize("batch_size", (1, 4, 16))
+@pytest.mark.parametrize("nproc,pid",
+                         [(n, p) for n in range(1, 5) for p in range(n)])
+def test_slice_framing_matches_per_word_framer(monkeypatch, nproc, pid,
+                                               batch_size):
+    """The slice framer yields the per-word framer's exact ``Block``
+    sequence (offset, count, words, padded) on every geometry: empty
+    and one-word streams, exact multiples of the global block and one
+    either side, with and without a base offset and pad word, from a
+    one-pass generator, and with a chunk (hosts other than 0) of one
+    word, a few words, and the default."""
+    g = batch_size * nproc
+    lengths = sorted({0, 1} | {k * g + d for k in (1, 2, 3)
+                               for d in (-1, 0, 1)})
+    for chunk in (1, 3, framing._CHUNK):
+        monkeypatch.setattr(framing, "_CHUNK", chunk)
+        for n in lengths:
+            for kw in ({}, {"pad_word": b"<pad>", "base_offset": 1000}):
+                def words():
+                    return (b"w%05d" % i for i in range(n))
+
+                got = [(b.offset, b.count, b.words, b.padded)
+                       for b in frame_blocks(words(), batch_size,
+                                             nproc=nproc, pid=pid, **kw)]
+                ref = [(b.offset, b.count, b.words, b.padded)
+                       for b in _per_word_frame_blocks(
+                           words(), batch_size, nproc=nproc, pid=pid, **kw)]
+                assert got == ref, (chunk, n, kw)
 
 
 def test_client_shard_word_blocks_delegates():
@@ -214,6 +283,34 @@ def test_producer_fault_reraised_with_offset():
     assert e.value.offset == 128
     assert isinstance(e.value.__cause__, ValueError)
     assert "offset 128" in str(e.value)
+    assert not _feed_threads()
+
+    # a source that breaks partway through the SECOND block: the first
+    # block still arrives, then the fault at the framing frontier (the
+    # end of the last framed block), and nothing after it
+    def breaks_in_block_two():
+        for i in range(100):
+            yield b"y%06d" % i
+        raise ValueError("truncated gzip")
+
+    for producers in (1, 0):
+        feed = CandidateFeed(breaks_in_block_two(), batch_size=64,
+                             producers=producers,
+                             registry=MetricsRegistry())
+        first = next(feed)
+        assert (first.offset, first.count) == (0, 64)
+        assert first.words == [b"y%06d" % i for i in range(64)]
+        with pytest.raises(FeedError) as e:
+            next(feed)
+        assert e.value.offset == 64, producers
+        assert isinstance(e.value.__cause__, ValueError)
+        if producers:
+            # the fault stands: no block is delivered after it
+            with pytest.raises(FeedError):
+                next(feed)
+        else:
+            assert next(feed, None) is None
+        feed.close()
     assert not _feed_threads()
 
 
@@ -381,6 +478,26 @@ def test_feed_wait_spans_are_the_starve_samples():
     assert sum(s["sum"] for s in snap) == pytest.approx(
         sum(r["t1"] - r["t0"] for r in waits))
     assert feed.tracer.args == {"feed": "t2"}
+
+
+@pytest.mark.parametrize("producers", (1, 0))
+def test_feed_frame_spans_count_each_framing_call(producers):
+    """Every framing call is a ``feed:frame`` span and one
+    ``dwpa_feed_frame_seconds`` observation: one per framed block, plus
+    the call that finds the stream's end."""
+    reg = MetricsRegistry()
+    feed = CandidateFeed((b"c%06d" % i for i in range(500)), batch_size=32,
+                         producers=producers, registry=reg, name="t3")
+    blocks = list(feed)
+    feed.close()
+    assert len(blocks) == 16
+    frames = feed.tracer.records("feed:frame")
+    assert len(frames) == len(blocks) + 1
+    assert reg.value("dwpa_feed_frame_seconds", feed="t3") == len(frames)
+    assert reg.value("dwpa_span_seconds", span="feed:frame") == len(frames)
+    snap = reg.snapshot()["dwpa_feed_frame_seconds"]["samples"]
+    assert sum(s["sum"] for s in snap) == pytest.approx(
+        sum(r["t1"] - r["t0"] for r in frames))
 
 
 def test_stage_times_are_the_engine_span_sums():
